@@ -2,10 +2,11 @@
 registry-derived MODEL_FLOPS into the EXPERIMENTS.md tables, plus the
 *measured* device-path benchmark (``bench_device`` → BENCH_device.json).
 
-Terms per (arch × shape × mesh), all per-chip:
-  compute_s    = HLO_FLOPs / 197e12       (bf16 peak, v5e)
-  memory_s     = HLO_bytes / 819e9        (HBM BW)
-  collective_s = collective_bytes / 50e9  (ICI link BW)
+Terms per (arch × shape × mesh), all per-chip of the dry run's TPU v5e
+target:
+  compute_s    = HLO_FLOPs / bf16 peak
+  memory_s     = HLO_bytes / HBM bandwidth
+  collective_s = collective_bytes / ICI link bandwidth
 MODEL_FLOPS = 6·N_active·D + 3·attn (train), 2·N_active·D + attn
 (prefill/decode); roofline_fraction = ideal_compute_time / bound.
 
@@ -13,7 +14,11 @@ MODEL_FLOPS = 6·N_active·D + 3·attn (train), 2·N_active·D + attn
 against the pre-fusion pipeline (XLA-scan chain, host round-trip, separate
 unpack/popcount/degree-feed/weighted passes) producing the *same outputs*,
 reports achieved logical bytes/s for both, and asserts the fused path's
-analytics stay bit-identical to the ``ref.py`` oracle.  Run standalone::
+analytics stay bit-identical to the ``ref.py`` oracle.  Its share of the
+HBM roofline uses the peaks of the device it ran on
+(``launch/hlo_analysis.DEVICE_PEAKS``); a device without published peaks
+there — the CPU among them — is an error.
+Run on the chip::
 
     PYTHONPATH=src python -m benchmarks.roofline_report --device --quick
 """
@@ -23,9 +28,8 @@ import json
 import os
 import time
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from repro.launch.hlo_analysis import (DRYRUN_TARGET, PEAKS_SOURCE,
+                                      device_peaks)
 
 DEVICE_JSON = "BENCH_device.json"
 
@@ -58,7 +62,8 @@ def refresh_model_flops(results: dict) -> None:
         chips = rec["chips"]
         r = rec["roofline"]
         bound = max(r["compute_s"], r["memory_s"], r["collective_s"])
-        ideal = rec["model_flops"] / (chips * PEAK_FLOPS)
+        peak = device_peaks(DRYRUN_TARGET)["bf16_flops"]
+        ideal = rec["model_flops"] / (chips * peak)
         r["roofline_fraction"] = ideal / bound if bound else 0.0
         total = h["flops"] * chips
         r["useful_flops_ratio"] = rec["model_flops"] / total if total else 0.0
@@ -128,13 +133,12 @@ def bench_device(quick: bool = False):
     pipeline: XLA-scan chain call, device→host round-trip, then host-side
     unpack + popcount + weighted reductions (exactly what the training
     example's ``snapshot_batch`` and the analytics ops used to do).  The
-    fused path is one compiled call.  On this CPU container both run
-    through XLA — the interpret-comparable measurement of the kernel
-    fusion itself; on TPU the same entry points lower through Mosaic.
+    fused path is one compiled call.
     Achieved bytes/s counts the logical chain traffic (K+2 planes of W
     words), the quantity the roofline's HBM term bounds.
     """
     import numpy as np
+    import jax
     import jax.numpy as jnp
     from repro.core import bitmaps as bmod
     from repro.kernels import delta_apply_fused
@@ -142,6 +146,8 @@ def bench_device(quick: bool = False):
     from repro.kernels.delta_apply import (delta_apply_chain,
                                            delta_apply_fused_ref)
 
+    dev = jax.devices()[0]
+    peaks = device_peaks(dev.device_kind)
     W = 1 << 14 if quick else 1 << 16     # words: 2^19 / 2^21 slots
     K = 8 if quick else 16
     reps = 5 if quick else 10
@@ -154,7 +160,7 @@ def bench_device(quick: bool = False):
     logical_bytes = (K + 2) * W * 4
 
     def fused():
-        out = delta_apply_fused(base, adds, dels, weights, impl="xla")
+        out = delta_apply_fused(base, adds, dels, weights)
         return (np.asarray(out.mask), np.asarray(out.pop),
                 np.asarray(out.accw), np.asarray(out.live))
 
@@ -174,7 +180,7 @@ def bench_device(quick: bool = False):
     # bit-identity vs the oracle (the acceptance gate for fused analytics)
     pb, pa, pd, pw, _ = _fused_pad(base, adds, dels, weights, 1024)
     rm, rp, ra, rl = delta_apply_fused_ref(pb, pa, pd, pw, block_w=1024)
-    fo = delta_apply_fused(base, adds, dels, weights, impl="xla")
+    fo = delta_apply_fused(base, adds, dels, weights)
     parity = bool(
         np.array_equal(np.asarray(fo.mask), np.asarray(rm[:W]))
         and np.array_equal(np.asarray(fo.pop), np.asarray(rp))
@@ -184,13 +190,15 @@ def bench_device(quick: bool = False):
     fused_gbps = logical_bytes / t_fused / 1e9
     base_gbps = logical_bytes / t_base / 1e9
     report = {
+        "platform": dev.platform, "device_kind": dev.device_kind,
         "W_words": W, "K": K, "slots": U,
         "logical_bytes_per_apply": logical_bytes,
         "fused_s": t_fused, "baseline_s": t_base,
         "fused_gbps": round(fused_gbps, 3),
         "baseline_gbps": round(base_gbps, 3),
         "speedup_fused_vs_baseline": round(t_base / t_fused, 3),
-        "hbm_fraction_of_v5e": round(fused_gbps * 1e9 / HBM_BW, 5),
+        "hbm_fraction_of_peak": fused_gbps * 1e9 / peaks["hbm_bytes_per_s"],
+        "peaks_source": PEAKS_SOURCE,
         "analytics_bit_identical_to_ref": parity,
     }
     with open(DEVICE_JSON, "w") as f:

@@ -24,7 +24,6 @@ def main() -> None:
     benches = [
         materialize_bench.bench_materialize,
         retrieval_bench.bench_retrieval,
-        roofline_report.bench_device,
         temporal_bench.bench_temporal,
         storage_bench.bench_storage,
         query_bench.bench_query,

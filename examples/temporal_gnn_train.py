@@ -25,6 +25,7 @@ from repro.core import GraphManager, SnapshotBatchLoader
 from repro.data.generators import churn_network
 from repro.models import common as mc
 from repro.models.gnn import GCNConfig, gnn_loss, gnn_param_defs
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.storage.checkpoint import restore_checkpoint, save_checkpoint
 from repro.storage.kv import LogFileKV
 from repro.training.optim import OPTIMIZERS
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("building historical trace + DeltaGraph index ...")
     uni, ev = churn_network(n_initial_edges=600, n_events=6000, seed=5)

@@ -60,6 +60,43 @@ def growing_network(n_events: int = 4000, seed: int = 0,
     return b.finalize()
 
 
+class _LiveOrder:
+    """Live edges in insertion order, with the i-th live one found in
+    O(log n): a Fenwick tree counts the live entries among insertion
+    positions ``0..cap-1``."""
+
+    def __init__(self, cap: int):
+        self._tree = [0] * (cap + 1)
+        self._step = 1 << cap.bit_length()
+        self._keys: list = []
+
+    def _add(self, pos: int, delta: int) -> None:
+        i = pos + 1
+        while i < len(self._tree):
+            self._tree[i] += delta
+            i += i & -i
+
+    def append(self, key) -> int:
+        pos = len(self._keys)
+        self._keys.append(key)
+        self._add(pos, 1)
+        return pos
+
+    def remove(self, pos: int) -> None:
+        self._add(pos, -1)
+
+    def nth(self, i: int):
+        """Key of the ``i``-th (0-based) live entry."""
+        pos, step, tree = 0, self._step, self._tree
+        while step:
+            nxt = pos + step
+            if nxt < len(tree) and tree[nxt] <= i:
+                pos = nxt
+                i -= tree[nxt]
+            step >>= 1
+        return self._keys[pos]
+
+
 def churn_network(n_initial_edges: int = 500, n_events: int = 4000,
                   seed: int = 0, p_delete: float = 0.4,
                   p_attr_update: float = 0.1, p_transient: float = 0.02,
@@ -71,14 +108,16 @@ def churn_network(n_initial_edges: int = 500, n_events: int = 4000,
     for n in range(n_nodes):
         b.add_node(n, 0, attrs={ATTR_NAMES[j]: float(rng.random())
                                 for j in range(n_attrs)})
-    live: dict[tuple[int, int], int] = {}
+    live: dict[tuple[int, int], tuple[int, int]] = {}   # key -> (slot, pos)
+    order = _LiveOrder(n_initial_edges + n_events)
     eid = 0
     for _ in range(n_initial_edges):
         u, v = rng.integers(0, n_nodes, 2)
         if u == v or (int(u), int(v)) in live or (int(v), int(u)) in live:
             continue
-        live[(int(u), int(v))] = b.add_edge(int(u), int(v), 1,
-                                            edge_id=("e", eid))
+        key = (int(u), int(v))
+        live[key] = (b.add_edge(*key, 1, edge_id=("e", eid)),
+                     order.append(key))
         eid += 1
     times = _times(rng, n_events, superlinear) + 2
     i = 0
@@ -97,16 +136,17 @@ def churn_network(n_initial_edges: int = 500, n_events: int = 4000,
                             float(rng.random()), t)
             emitted += 1
         elif live and r < p_transient + p_attr_update + p_delete:
-            key = list(live.keys())[int(rng.integers(0, len(live)))]
-            slot = live.pop(key)
+            slot, pos = live.pop(order.nth(int(rng.integers(0, len(live)))))
+            order.remove(pos)
             b.delete_edge_slot(slot, t)
             emitted += 1
         else:
             u, v = rng.integers(0, n_nodes, 2)
             if u == v or (int(u), int(v)) in live or (int(v), int(u)) in live:
                 continue
-            live[(int(u), int(v))] = b.add_edge(int(u), int(v), t,
-                                                edge_id=("e", eid))
+            key = (int(u), int(v))
+            live[key] = (b.add_edge(*key, t, edge_id=("e", eid)),
+                         order.append(key))
             eid += 1
             emitted += 1
     return b.finalize()

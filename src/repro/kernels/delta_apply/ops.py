@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import policy
-from .delta_apply import delta_apply_chain_pallas, delta_apply_fused_pallas
+from .delta_apply import (delta_apply_chain_batched_pallas,
+                          delta_apply_chain_pallas, delta_apply_fused_pallas)
 from .ref import (delta_apply_chain_prefix_ref, delta_apply_chain_ref,
                   delta_apply_fused_ref)
 
@@ -73,13 +74,13 @@ def delta_apply_chain(base: jnp.ndarray, adds: jnp.ndarray, dels: jnp.ndarray,
                       *, impl: str | None = None, block_w: int = 1024,
                       interpret: bool | None = None) -> jnp.ndarray:
     impl, interpret = policy.resolve(impl, interpret)
+    W = base.shape[0]
+    Kp = _bucket(adds.shape[0])
+    adds, dels = _pad_axis(adds, 0, Kp), _pad_axis(dels, 0, Kp)
     if impl == "xla":
-        W = base.shape[0]
         Wp = -(-W // _W_ALIGN) * _W_ALIGN
-        Kp = _bucket(adds.shape[0])
-        out = _chain_jit(_pad_axis(base, 0, Wp),
-                         _pad_axis(_pad_axis(adds, 1, Wp), 0, Kp),
-                         _pad_axis(_pad_axis(dels, 1, Wp), 0, Kp))
+        out = _chain_jit(_pad_axis(base, 0, Wp), _pad_axis(adds, 1, Wp),
+                         _pad_axis(dels, 1, Wp))
         return out[:W]
     return delta_apply_chain_pallas(base, adds, dels, block_w=block_w,
                                     interpret=interpret)
@@ -89,26 +90,30 @@ def delta_apply_chain_batched(bases: jnp.ndarray, adds: jnp.ndarray,
                               dels: jnp.ndarray, *, impl: str | None = None,
                               block_w: int = 1024,
                               interpret: bool | None = None) -> jnp.ndarray:
-    """Vmapped multi-snapshot apply: ``B`` sibling chains in one call.
+    """Multi-snapshot apply: ``B`` sibling chains in one call.
 
     ``bases [B, W]``, ``adds/dels [B, K, W]`` (chains zero-padded to a
     common ``K``; an all-zero ``(adds, dels)`` row is the identity step).
     Sibling branches after a plan Fork execute as one batched pass — one
     kernel launch and one sweep over the stacked bit-planes instead of
-    ``B`` sequential chain calls.
+    ``B`` sequential chain calls (the Pallas kernel carries ``B`` as a
+    grid axis).
     """
     impl, interpret = policy.resolve(impl, interpret)
+    B, K, W = adds.shape
+    Bp, Kp = _bucket(B), _bucket(K)
+    bases = _pad_axis(bases, 0, Bp)
+    adds, dels = (_pad_axis(_pad_axis(x, 1, Kp), 0, Bp) for x in (adds, dels))
     if impl == "xla":
-        B, K, W = adds.shape
         Wp = -(-W // _W_ALIGN) * _W_ALIGN
-        Bp, Kp = _bucket(B), _bucket(K)
-        out = _chain_batched_jit(
-            _pad_axis(_pad_axis(bases, 1, Wp), 0, Bp),
-            _pad_axis(_pad_axis(_pad_axis(adds, 2, Wp), 1, Kp), 0, Bp),
-            _pad_axis(_pad_axis(_pad_axis(dels, 2, Wp), 1, Kp), 0, Bp))
-        return out[:B, :W]
-    return jax.vmap(lambda b, a, d: delta_apply_chain_pallas(
-        b, a, d, block_w=block_w, interpret=interpret))(bases, adds, dels)
+        out = _chain_batched_jit(_pad_axis(bases, 1, Wp),
+                                 _pad_axis(adds, 2, Wp),
+                                 _pad_axis(dels, 2, Wp))
+    else:
+        out = delta_apply_chain_batched_pallas(bases, adds, dels,
+                                               block_w=block_w,
+                                               interpret=interpret)
+    return out[:B, :W]
 
 
 def delta_apply_chain_prefix(base: jnp.ndarray, adds: jnp.ndarray,
@@ -183,6 +188,23 @@ _fused_batched_xla_jit = jax.jit(
     static_argnames=("block_w", "emit_live"))
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("block_w", "interpret", "emit_live"))
+def _fused_batched_pallas(bases, adds, dels, weights, *, block_w, interpret,
+                          emit_live):
+    """The Pallas kernel works on bit-planes (plane ``b`` = bit ``b`` of
+    every word): slot-order weights go in as ``[32, W]`` planes and the
+    live indicator comes back to slot order ``[B, W*32]``."""
+    Wp = bases.shape[-1]
+    planes = None if weights is None else weights.reshape(Wp, 32).T
+    mask, pop, accw, live = delta_apply_fused_pallas(
+        bases, adds, dels, planes, block_w=block_w, interpret=interpret,
+        emit_live=emit_live)
+    if live is not None:
+        live = live.transpose(0, 2, 1).reshape(live.shape[0], Wp * 32)
+    return mask, pop, accw, live
+
+
 def _fused_pad(base, adds, dels, weights, block_w):
     """Pad W to a block multiple and K to its bucket — once, identically,
     for every impl, so partials line up bit-for-bit across impls."""
@@ -219,9 +241,10 @@ def delta_apply_fused(base: jnp.ndarray, adds: jnp.ndarray,
         mask, pop, accw, live = _fused_xla_jit(
             base, adds, dels, weights, block_w=block_w, emit_live=emit_live)
     else:
-        mask, pop, accw, live = delta_apply_fused_pallas(
-            base, adds, dels, weights, block_w=block_w, interpret=interpret,
-            emit_live=emit_live)
+        outs = _fused_batched_pallas(
+            base[None], adds[None], dels[None], weights, block_w=block_w,
+            interpret=interpret, emit_live=emit_live)
+        mask, pop, accw, live = (None if x is None else x[0] for x in outs)
     return FusedOut(mask[:W], pop, accw[:W],
                     live[:W * 32] if live is not None else None)
 
@@ -234,8 +257,8 @@ def delta_apply_fused_batched(bases: jnp.ndarray, adds: jnp.ndarray,
                               emit_live: bool = True) -> FusedOut:
     """Batched fused apply+analytics: ``bases [B, W]``, ``adds/dels
     [B, K, W]``, one shared ``weights [W*32]`` — B chains land and emit
-    their analytics partials in a single vmapped pass (B is bucketed;
-    padded rows are dropped from every output)."""
+    their analytics partials in a single pass (B is bucketed; padded rows
+    are dropped from every output)."""
     impl, interpret = policy.resolve(impl, interpret)
     B = bases.shape[0]
     bases, adds, dels, weights, W = _fused_pad(bases, adds, dels, weights,
@@ -248,9 +271,8 @@ def delta_apply_fused_batched(bases: jnp.ndarray, adds: jnp.ndarray,
         mask, pop, accw, live = _fused_batched_xla_jit(
             bases, adds, dels, weights, block_w=block_w, emit_live=emit_live)
     else:
-        mask, pop, accw, live = jax.vmap(
-            lambda b, a, d: delta_apply_fused_pallas(
-                b, a, d, weights, block_w=block_w, interpret=interpret,
-                emit_live=emit_live))(bases, adds, dels)
+        mask, pop, accw, live = _fused_batched_pallas(
+            bases, adds, dels, weights, block_w=block_w,
+            interpret=interpret, emit_live=emit_live)
     return FusedOut(mask[:B, :W], pop[:B], accw[:B, :W],
                     live[:B, :W * 32] if live is not None else None)

@@ -26,6 +26,14 @@ def delta_apply_chain_ref(base: jnp.ndarray, adds: jnp.ndarray,
     return out
 
 
+def _halving_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum the trailing axis (a power of two) by repeated halving."""
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
+
+
 def delta_apply_fused_ref(base: jnp.ndarray, adds: jnp.ndarray,
                           dels: jnp.ndarray,
                           weights: jnp.ndarray | None = None, *,
@@ -39,9 +47,10 @@ def delta_apply_fused_ref(base: jnp.ndarray, adds: jnp.ndarray,
     * ``mask  [W] u32``    — the landed chain state,
     * ``pop   [G] i32``    — per-block popcount partials,
     * ``accw  [W] f32``    — per-word weighted partials (bits of word w
-      dotted with its 32 weights; plain per-word popcount when no
-      weights) — per-word grouping fixes the float reduction order, so
-      the Pallas kernel reproduces these bit-for-bit,
+      dotted with its 32 weights, summed by halving: ``x[b] + x[b+16]``,
+      then ``+ x[b+8]``, ...; plain per-word popcount when no weights) —
+      the fixed grouping and order let the Pallas kernel reproduce these
+      bit-for-bit,
     * ``live  [W*32] f32`` — unpacked membership (``None`` unless
       ``emit_live``), the segment_sum degree-reduction feed.
     """
@@ -54,7 +63,7 @@ def delta_apply_fused_ref(base: jnp.ndarray, adds: jnp.ndarray,
     pop = (jax.lax.population_count(m).astype(jnp.int32)
            .reshape(G, block_w).sum(axis=1))
     if weights is not None:
-        accw = (bits * weights.reshape(W, 32)).sum(axis=1)
+        accw = _halving_sum(bits * weights.reshape(W, 32))
     else:
         accw = bits.sum(axis=1)
     live = bits.reshape(-1) if emit_live else None

@@ -69,7 +69,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            causal: bool = True, window: int | None = None,
                            q_offset: int = 0, scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """q: [B, H, Sq, D]; k, v: [B, H, Sk, D] (GQA pre-broadcast by ops.py)."""
     B, H, Sq, D = q.shape
     Sk = k.shape[2]

@@ -2,21 +2,18 @@
 
 Every kernel family (``delta_apply``, ``segment_sum``, ``flash_attention``)
 exposes ``impl=`` (``"pallas"`` | ``"xla"``) and, for the Pallas path,
-``interpret=``.  Before this module existed the entry points hardcoded
-``interpret=True`` (the CPU-container test default) and every caller
-threaded ``impl`` flags by hand — a production TPU deployment had to touch
-each call site.  The policy is now resolved in one place:
+``interpret=``.  The policy is resolved in one place:
 
-* ``REPRO_KERNEL=pallas|xla`` pins the implementation for every kernel
-  entry point that is not explicitly overridden at the call site;
-* unset, the default is ``pallas`` on TPU backends and ``xla`` elsewhere
-  (CPU has no Mosaic compiler — the XLA path *is* the fast path there);
-* ``interpret`` (Pallas only) resolves to ``False`` exactly on TPU; any
-  other backend runs the kernel through the Pallas interpreter, which is
-  correct but slow — tests use it for parity, production never should.
+* on a TPU backend the only default is the compiled Pallas kernel,
+  ``("pallas", False)``; an environment override that asks for XLA or
+  for interpret mode there raises instead of quietly hiding the device;
+* elsewhere ``REPRO_KERNEL=pallas|xla`` pins the implementation (default
+  ``xla`` — CPU has no Mosaic compiler, the XLA path *is* the fast path
+  there) and ``REPRO_KERNEL_INTERPRET=0|1`` the interpret mode (default
+  on: the Pallas interpreter is correct but slow, the parity suite's
+  mode).
 
-``REPRO_KERNEL_INTERPRET=0|1`` force-overrides interpret resolution (used
-by the parity suite to exercise both paths on one host).
+Explicit call-site values always win, on every backend.
 """
 from __future__ import annotations
 
@@ -31,29 +28,40 @@ def backend() -> str:
     return jax.default_backend()
 
 
+def _env(name: str) -> str:
+    return os.environ.get(name, "").strip().lower()
+
+
 def default_impl() -> str:
-    """Policy default when neither the call site nor the env pins one."""
-    env = os.environ.get("REPRO_KERNEL", "").strip().lower()
-    if env:
-        if env not in VALID_IMPLS:
-            raise ValueError(
-                f"REPRO_KERNEL={env!r} invalid; choose from {VALID_IMPLS}")
-        return env
-    return "pallas" if backend() == "tpu" else "xla"
+    """Policy default when the call site pins no implementation."""
+    env = _env("REPRO_KERNEL")
+    if env and env not in VALID_IMPLS:
+        raise ValueError(
+            f"REPRO_KERNEL={env!r} invalid; choose from {VALID_IMPLS}")
+    if backend() == "tpu":
+        if env not in ("", "pallas"):
+            raise ValueError(f"REPRO_KERNEL={env!r} on a TPU backend: the "
+                             "device path runs the compiled Pallas kernels")
+        return "pallas"
+    return env or "xla"
 
 
 def default_interpret() -> bool:
     """Pallas interpret-mode default: real Mosaic compile only on TPU."""
-    env = os.environ.get("REPRO_KERNEL_INTERPRET", "").strip()
-    if env:
-        return env not in ("0", "false", "False")
-    return backend() != "tpu"
+    env = _env("REPRO_KERNEL_INTERPRET")
+    interpret = env not in ("0", "false") if env else None
+    if backend() == "tpu":
+        if interpret:
+            raise ValueError("REPRO_KERNEL_INTERPRET asks for the Pallas "
+                             "interpreter on a TPU backend")
+        return False
+    return True if interpret is None else interpret
 
 
 def resolve(impl: str | None = None, interpret: bool | None = None
             ) -> tuple[str, bool]:
     """Resolve ``(impl, interpret)``: explicit call-site values win, then
-    ``REPRO_KERNEL`` / ``REPRO_KERNEL_INTERPRET``, then backend detection."""
+    the backend's default and the environment, as set out above."""
     if impl is None:
         impl = default_impl()
     elif impl not in VALID_IMPLS:

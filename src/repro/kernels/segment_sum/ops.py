@@ -17,6 +17,7 @@ def bucket_edges(seg_ids: np.ndarray, num_segments: int, block_n: int
 
     Returns (order, local_ids, max_edges): gather ``data[order]`` then
     reshape to [NB, ME, D]; ``local_ids`` is [NB, ME] with -1 padding.
+    ME is the largest bucket rounded up to whole 128-lane rows.
     """
     seg_ids = np.asarray(seg_ids)
     order = np.argsort(seg_ids, kind="stable")
@@ -24,7 +25,7 @@ def bucket_edges(seg_ids: np.ndarray, num_segments: int, block_n: int
     NB = -(-num_segments // block_n)
     bucket_of = sorted_ids // block_n
     counts = np.bincount(bucket_of, minlength=NB)
-    ME = max(int(counts.max(initial=0)), 1)
+    ME = -(-max(int(counts.max(initial=0)), 1) // 128) * 128
     out_order = np.zeros((NB, ME), np.int64)
     local = np.full((NB, ME), -1, np.int32)
     starts = np.zeros(NB + 1, np.int64)
@@ -56,9 +57,10 @@ def segment_sum(data: jnp.ndarray, seg_ids, num_segments: int, *,
         if buckets is None:
             buckets = bucket_edges(np.asarray(seg_ids), num_segments, block_n)
         out_order, local, ME = buckets
-        NB = local.shape[0]
-        gathered = data[out_order.reshape(-1)].reshape(NB, ME, data.shape[-1])
+        NB, D = local.shape[0], data.shape[-1]
+        gathered = jnp.take(data.T, jnp.asarray(out_order.reshape(-1)),
+                            axis=1).reshape(D, NB, ME).transpose(1, 0, 2)
         out = segment_sum_bucketed(gathered, jnp.asarray(local),
                                    block_n=block_n, interpret=interpret)
-        return out.reshape(NB * block_n, data.shape[-1])[:num_segments]
+        return out.transpose(0, 2, 1).reshape(NB * block_n, D)[:num_segments]
     raise ValueError(f"unknown impl {impl!r}")

@@ -12,14 +12,10 @@ import jax           # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from ..configs.registry import get_cell, list_cells  # noqa: E402
-from ..runtime import compat  # noqa: E402
 from . import hlo_analysis  # noqa: E402
 from .mesh import make_production_mesh  # noqa: E402
 
-# TPU v5e constants (roofline targets; the container itself is CPU-only)
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+PEAKS = hlo_analysis.device_peaks(hlo_analysis.DRYRUN_TARGET)
 
 
 def _shardings(mesh, pspec_tree):
@@ -51,7 +47,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, *,
         return rec
     in_sh = _shardings(mesh, cell.pspecs)
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(cell.fn, in_shardings=in_sh).lower(*cell.args)
         t1 = time.time()
         compiled = lowered.compile()
@@ -83,9 +79,10 @@ def run_cell(arch: str, shape: str, multi_pod: bool, *,
                                         "collective_bytes", "collectives")}
         # roofline terms (per device; HLO is the per-device SPMD program)
         rec["roofline"] = {
-            "compute_s": h["flops"] / PEAK_FLOPS,
-            "memory_s": h["hbm_bytes"] / HBM_BW,
-            "collective_s": h["collective_bytes"] / ICI_BW,
+            "compute_s": h["flops"] / PEAKS["bf16_flops"],
+            "memory_s": h["hbm_bytes"] / PEAKS["hbm_bytes_per_s"],
+            "collective_s": (h["collective_bytes"]
+                             / PEAKS["ici_link_bytes_per_s"]),
         }
         dom = max(rec["roofline"], key=rec["roofline"].get)
         rec["roofline"]["bottleneck"] = dom
@@ -94,7 +91,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, *,
             cell.flops_model / total_hlo_flops if total_hlo_flops else 0.0)
         bound = max(rec["roofline"]["compute_s"], rec["roofline"]["memory_s"],
                     rec["roofline"]["collective_s"])
-        ideal = cell.flops_model / (chips * PEAK_FLOPS)
+        ideal = cell.flops_model / (chips * PEAKS["bf16_flops"])
         rec["roofline"]["roofline_fraction"] = (ideal / bound) if bound else 0.0
     rec["status"] = "ok"
     return rec

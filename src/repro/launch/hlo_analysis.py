@@ -35,8 +35,28 @@ _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*?\))?\s*->.*{\s*$")
 _CALL_ATTR_RE = re.compile(r"(?:condition|body|calls|to_apply|branch_computations)="
                            r"(%?[\w.\-]+|\{[^}]*\})")
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-                "collective-permute")
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+# Published peaks of one chip, keyed by ``jax.Device.device_kind``: the
+# roofline's denominators, for the dry run's target and for measured runs.
+PEAKS_SOURCE = 'Google Cloud documentation, "TPU v5e", per-chip specifications'
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bytes_per_s": 819e9,
+                    # 1,600 Gbit/s of ICI per chip over 4 links
+                    "ici_link_bytes_per_s": 50e9},
+}
+DRYRUN_TARGET = "TPU v5 lite"   # the meshes launch/dryrun.py compiles for
+
+
+def device_peaks(kind: str) -> dict:
+    """Peaks of a device kind; an unknown kind raises instead of guessing."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; add "
+                       f"them to DEVICE_PEAKS with their source") from None
 
 
 def shape_bytes(text: str) -> int:
@@ -204,7 +224,7 @@ def analyze(text: str) -> dict:
                     if op in shapes:
                         in_b += shape_bytes(shapes[op])
                 c_hbm += out_b + in_b
-            for kind in _COLLECTIVES:
+            for kind in COLLECTIVES:
                 if ins.opcode == kind or ins.opcode == kind + "-start":
                     coll[kind] += out_b * mult
         flops += c_fl * mult

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from ..configs.registry import family_of, get_arch, reduced_config
 from ..models import common as mc
+from ..runtime.compile_cache import enable_compile_cache
 
 
 def serve_snapshots(n_events: int, budget_mb: float, queries: int,
@@ -625,6 +626,7 @@ def main() -> None:
                              "masks"),
                     help="evolve mode: incremental operator")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "server" or (args.mode == "query" and args.port > 0):
         serve_server(args.events, args.port, codec=args.codec,
                      kv=args.kv, kv_dir=args.kv_dir, hot_mb=args.hot_mb,

@@ -324,21 +324,30 @@ class ShardProc:
                 pass
 
 
-def spawn_shard_procs(n: int, *, hot_mb: float = 64.0,
-                      ready_timeout_s: float = 60.0) -> list[ShardProc]:
-    """Spawn ``n`` shardd processes and wait for their ready lines.
-
-    Children are full interpreters (``sys.executable -m
-    repro.launch.shardd``) — real isolation, SIGKILL-able — with
-    ``PYTHONPATH`` extended so the child resolves the same ``repro``
-    tree as the parent.
-    """
+def child_env() -> dict[str, str]:
+    """Environment of a shardd child: ``PYTHONPATH`` extended so it
+    resolves the same ``repro`` tree as the parent, and ``JAX_PLATFORMS``
+    pinned to the CPU — shardd only fetches blobs, and an accelerator
+    belongs to one process at a time (the coordinator)."""
     import repro
     # repro is a namespace package: resolve its source root via __path__
     src_dir = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_dir + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+def spawn_shard_procs(n: int, *, hot_mb: float = 64.0,
+                      ready_timeout_s: float = 60.0) -> list[ShardProc]:
+    """Spawn ``n`` shardd processes and wait for their ready lines.
+
+    Children are full interpreters (``sys.executable -m
+    repro.launch.shardd``) — real isolation, SIGKILL-able — started with
+    :func:`child_env`.
+    """
+    env = child_env()
     procs = []
     for _ in range(n):
         procs.append(subprocess.Popen(

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from ..configs.registry import family_of, get_arch, reduced_config
 from ..models import common as mc
+from ..runtime.compile_cache import enable_compile_cache
 from ..storage.checkpoint import restore_checkpoint, save_checkpoint
 from ..storage.kv import LogFileKV
 from ..training.optim import OPTIMIZERS, warmup_cosine
@@ -90,6 +91,7 @@ def main() -> None:
     ap.add_argument("--grad-compression", default=None,
                     choices=[None, "bf16", "int8"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.full_config:
         cfg, opt_name = get_arch(args.arch)

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat
 from .staging import DeviceStager, stream_chunk_k
 from ..core import bitmaps as bmod
 from ..core import planir
@@ -754,9 +753,11 @@ def plan_to_chain_sharded(dg: DeltaGraph, plan: Plan, Pn: int, pool=None
     return (base_n, base_e), stacks
 
 
+@functools.lru_cache(maxsize=None)
 def make_retrieval_fn(mesh: Mesh, axis: str = "data"):
-    """Builds the shard_map'ed chain applier.  Each device owns one row of
-    the [P, Wp] layout; the chain is applied locally — no collectives."""
+    """Builds the shard_map'ed chain applier (one per mesh, so its compiled
+    programs are reused).  Each device owns one row of the [P, Wp] layout;
+    the chain is applied locally — no collectives."""
 
     def _local(base, adds, dels):
         def step(m, ad):
@@ -765,24 +766,29 @@ def make_retrieval_fn(mesh: Mesh, axis: str = "data"):
         out, _ = jax.lax.scan(step, base, (adds, dels))
         return out
 
-    shard = compat.shard_map(
+    shard = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(axis, None), P(None, axis, None), P(None, axis, None)),
         out_specs=P(axis, None))
     return jax.jit(shard)
 
 
-def execute_singlepoint_sharded(dg: DeltaGraph, t: int, mesh: Mesh, *,
-                                axis: str = "data", pool=None,
-                                use_current: bool = True
-                                ) -> tuple[np.ndarray, np.ndarray]:
-    """Distributed retrieval: requires ``dg.P == mesh.shape[axis]`` and the
-    word_cyclic partitioner (storage partitions == compute partitions, the
-    paper's aligned deployment)."""
+def land_singlepoint_sharded(dg: DeltaGraph, t: int, mesh: Mesh, *,
+                             axis: str = "data", pool=None,
+                             use_current: bool = True
+                             ) -> tuple[jax.Array, jax.Array]:
+    """Land the node and edge planes of snapshot ``t`` in the ``[P, Wp]``
+    word_cyclic layout, row ``p`` placed on (and applied by) the mesh's
+    device ``p``.  Aligned fetches need ``dg.P == mesh.shape[axis]`` under
+    the word_cyclic partitioner (storage partitions == compute partitions,
+    the paper's aligned deployment); any other index is scattered into
+    the layout on the host."""
     Pn = mesh.shape[axis]
     plan = dg.plan_singlepoint(t, NO_ATTRS, use_current)
     U_n, U_e = dg.universe.num_nodes, dg.universe.num_edges
     fn = make_retrieval_fn(mesh, axis)
+    rows = NamedSharding(mesh, P(axis, None))
+    chain_rows = NamedSharding(mesh, P(None, axis, None))
     aligned = dg.P == Pn and dg.partition_fn_name == "word_cyclic"
     if aligned:
         # aligned deployment: each partition's sub-payloads are fetched
@@ -797,15 +803,33 @@ def execute_singlepoint_sharded(dg: DeltaGraph, t: int, mesh: Mesh, *,
             for base, ix_a, ix_d, U in (
                 (base_n, [c[0] for c in chain], [c[1] for c in chain], U_n),
                 (base_e, [c[2] for c in chain], [c[3] for c in chain], U_e)))
-    outs = []
-    for base, adds, dels, U in sides:
-        b = sharded_base(np.asarray(base), Pn)
-        out = np.asarray(fn(jnp.asarray(b), jnp.asarray(adds), jnp.asarray(dels)))
-        outs.append(bmod.np_unpack(unshard(out, bmod.num_words(U)), U))
-    nm, em = outs
+    out_n, out_e = (
+        fn(jax.device_put(sharded_base(np.asarray(base), Pn), rows),
+           jax.device_put(adds, chain_rows), jax.device_put(dels, chain_rows))
+        for base, adds, dels, _ in sides)
+    return out_n, out_e
+
+
+def sharded_masks(dg: DeltaGraph, out_n, out_e
+                  ) -> tuple[np.ndarray, np.ndarray]:
+    """``(node_mask, edge_mask)`` bool arrays from landed ``[P, Wp]``
+    planes, transient slots cleared."""
+    U_n, U_e = dg.universe.num_nodes, dg.universe.num_edges
+    nm, em = (bmod.np_unpack(unshard(np.asarray(out), bmod.num_words(U)), U)
+              for out, U in ((out_n, U_n), (out_e, U_e)))
     em &= ~dg.universe.edge_transient[:U_e]
     nm &= ~dg.universe.node_transient[:U_n]
     return nm, em
+
+
+def execute_singlepoint_sharded(dg: DeltaGraph, t: int, mesh: Mesh, *,
+                                axis: str = "data", pool=None,
+                                use_current: bool = True
+                                ) -> tuple[np.ndarray, np.ndarray]:
+    """Distributed retrieval (:func:`land_singlepoint_sharded`), returned
+    as host bool masks."""
+    return sharded_masks(dg, *land_singlepoint_sharded(
+        dg, t, mesh, axis=axis, pool=pool, use_current=use_current))
 
 
 def lowered_retrieval_hlo(mesh: Mesh, K: int, Wp: int, axis: str = "data") -> str:

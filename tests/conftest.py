@@ -1,6 +1,11 @@
 import os
 import sys
 
+# Tests run on the CPU (Pallas kernels in interpret mode), also on a host
+# that holds an accelerator: the device belongs to one process at a time,
+# and a test run must never take it.  Set before anything imports JAX.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
 # real single-device CPU; only launch/dryrun.py (and the subprocesses in
 # test_distributed.py) fake 512/8 devices.

@@ -36,11 +36,11 @@ def test_sharded_retrieval_correct_and_collective_free():
         from repro.data.generators import churn_network
         from repro.runtime.jax_exec import (execute_singlepoint_sharded,
                                             lowered_retrieval_hlo)
-        from repro.runtime import compat
         uni, ev = churn_network(n_initial_edges=150, n_events=900, seed=43)
         gm = GraphManager(uni, ev, L=80, k=2, num_partitions=8,
                           partition_fn="word_cyclic")
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(2)
         for t in rng.integers(0, int(ev.time[-1]) + 3, 5):
             t = int(t)
@@ -67,16 +67,16 @@ def test_multi_device_train_step_runs():
         from repro.models.transformer import model as tm
         from repro.training.optim import OPTIMIZERS
         from repro.training.trainer import make_train_step
-        from repro.runtime import compat
         cfg = reduced_config("yi-34b")
         params = mc.init_params(tm.param_defs(cfg), jax.random.PRNGKey(0))
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         tokens = jnp.asarray(np.random.default_rng(0).integers(
             0, cfg.vocab, (8, 16)), jnp.int32)
         opt = OPTIMIZERS["adamw"](lr=1e-3)
         state = opt[0](params)
         step = make_train_step(lambda p, b: tm.loss_fn(p, b, cfg), opt)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             tok_sh = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
             p2, s2, m = jax.jit(step)(params, state, {"tokens": tok_sh})
         assert np.isfinite(float(m["loss"]))

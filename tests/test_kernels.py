@@ -20,7 +20,8 @@ def test_delta_apply_sweep(W, K):
     dels = RNG.integers(0, 2 ** 32, (K, W), dtype=np.uint32)
     ref = delta_apply_chain(jnp.array(base), jnp.array(adds), jnp.array(dels))
     got = delta_apply_chain_pallas(jnp.array(base), jnp.array(adds),
-                                   jnp.array(dels), block_w=256)
+                                   jnp.array(dels), block_w=256,
+                                   interpret=True)
     assert np.array_equal(np.asarray(ref), np.asarray(got))
 
 
@@ -208,6 +209,24 @@ def test_policy_defaults_on_tpu(monkeypatch):
     monkeypatch.delenv("REPRO_KERNEL", raising=False)
     monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
     monkeypatch.setattr(policy, "backend", lambda: "tpu")
+    assert policy.resolve() == ("pallas", False)
+
+
+@pytest.mark.parametrize("var,value", [("REPRO_KERNEL_INTERPRET", "1"),
+                                       ("REPRO_KERNEL", "xla")])
+def test_policy_tpu_refuses_env_fallback(monkeypatch, var, value):
+    """On a TPU no environment setting may route around the compiled
+    kernel; a call site that asks explicitly still wins."""
+    from repro.kernels import policy
+    monkeypatch.delenv("REPRO_KERNEL", raising=False)
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
+    monkeypatch.setattr(policy, "backend", lambda: "tpu")
+    monkeypatch.setenv(var, value)
+    with pytest.raises(ValueError, match="TPU"):
+        policy.resolve()
+    assert policy.resolve("xla", True) == ("xla", True)
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
+    monkeypatch.setenv("REPRO_KERNEL", "pallas")
     assert policy.resolve() == ("pallas", False)
 
 
