@@ -32,6 +32,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from ..runtime.spans import span
 from ..storage import columnar as col
 from ..storage.kv import KVStore
 from . import diff_functions
@@ -806,17 +807,19 @@ class DeltaGraph:
 
     def plan_singlepoint(self, t: int, options: AttrOptions = NO_ATTRS,
                          use_current: bool = True) -> Plan:
-        virtuals = {("t", t): self._virtual_edges(t, options)}
-        sources = self._sources(use_current, options)
-        starts = {n: 0.0 for n, _ in sources}
-        dist, prev = self._dijkstra(starts, options, virtuals, use_current)
-        target = ("t", t)
-        if target not in dist:
-            raise RuntimeError(f"no retrieval path for t={t}")
-        b = PlanBuilder()
-        self._emit_chain(b, prev, dict(sources), target)
-        b.target(t, target)
-        return b.build()
+        with span("retrieve.plan"):
+            virtuals = {("t", t): self._virtual_edges(t, options)}
+            sources = self._sources(use_current, options)
+            starts = {n: 0.0 for n, _ in sources}
+            dist, prev = self._dijkstra(starts, options, virtuals,
+                                        use_current)
+            target = ("t", t)
+            if target not in dist:
+                raise RuntimeError(f"no retrieval path for t={t}")
+            b = PlanBuilder()
+            self._emit_chain(b, prev, dict(sources), target)
+            b.target(t, target)
+            return b.build()
 
     def plan_node(self, nid: int, options: AttrOptions = NO_ATTRS) -> Plan:
         """Plan retrieval of a *skeleton* node's (virtual) graph — used for
@@ -836,6 +839,11 @@ class DeltaGraph:
         times = list(dict.fromkeys(times))  # dedup, keep order
         if len(times) == 1:
             return self.plan_singlepoint(times[0], options, use_current)
+        with span("retrieve.plan"):
+            return self._plan_steiner(times, options, use_current)
+
+    def _plan_steiner(self, times: list, options: AttrOptions,
+                      use_current: bool) -> Plan:
         virtuals: dict[Any, list] = {}
         for t in times:
             virtuals[("t", t)] = self._virtual_edges(t, options)
@@ -923,12 +931,6 @@ class DeltaGraph:
         return self._decode_delta(blobs, len(keys), len(na_keys))
 
     def _decode_delta(self, blobs: list, n_struct: int, n_na: int) -> Delta:
-        structs = [col.decode_delta_struct(b) for b in blobs[:n_struct]]
-        na_blobs = blobs[n_struct: n_struct + n_na]
-        ea_blobs = blobs[n_struct + n_na:]
-        nas = [col.decode_attr(b) for b in na_blobs if b is not None]
-        eas = [col.decode_attr(b) for b in ea_blobs if b is not None]
-
         def cat(field):
             return np.concatenate([s[field] for s in structs]) if structs else np.zeros(0, np.int32)
 
@@ -940,8 +942,14 @@ class DeltaGraph:
                              np.concatenate([a.new for a in parts]),
                              np.concatenate([a.old for a in parts]))
 
-        return Delta(cat("node_add"), cat("node_del"), cat("edge_add"),
-                     cat("edge_del"), cat_attr(nas), cat_attr(eas))
+        with span("codec.decode"):
+            structs = [col.decode_delta_struct(b) for b in blobs[:n_struct]]
+            na_blobs = blobs[n_struct: n_struct + n_na]
+            ea_blobs = blobs[n_struct + n_na:]
+            nas = [col.decode_attr(b) for b in na_blobs if b is not None]
+            eas = [col.decode_attr(b) for b in ea_blobs if b is not None]
+            return Delta(cat("node_add"), cat("node_del"), cat("edge_add"),
+                         cat("edge_del"), cat_attr(nas), cat_attr(eas))
 
     def _elist_keys(self, pid: int, options: AttrOptions,
                     transient: bool = False,
@@ -965,14 +973,14 @@ class DeltaGraph:
     def _decode_elist(keys: list, blobs: list
                       ) -> dict[str, dict[str, np.ndarray]]:
         out: dict[str, list[dict[str, np.ndarray]]] = {}
-        for (pkey, blob) in zip(keys, blobs):
-            if blob is not None:
-                out.setdefault(pkey[2], []).append(col.unpack_arrays(blob))
-        merged = {}
-        for comp, parts in out.items():
-            merged[comp] = {k: np.concatenate([p[k] for p in parts])
-                            for k in parts[0]}
-        return merged
+        with span("codec.decode"):
+            for (pkey, blob) in zip(keys, blobs):
+                if blob is not None:
+                    out.setdefault(pkey[2], []).append(
+                        col.unpack_arrays(blob))
+            return {comp: {k: np.concatenate([p[k] for p in parts])
+                           for k in parts[0]}
+                    for comp, parts in out.items()}
 
     def _apply_elist(self, state: MaterializedState,
                      comps: dict[str, dict[str, np.ndarray]],

@@ -34,6 +34,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
+from ..runtime.spans import span
 from ..storage import columnar as col
 from .events import (EV_DEL_EDGE, EV_DEL_NODE, EV_NEW_EDGE, EV_NEW_NODE,
                      MaterializedState, apply_events)
@@ -135,11 +136,12 @@ class IntervalSlicer:
         comps = self._comps.get(i)
         if comps is None:
             fut = self._futs.pop(i, None)
-            if fut is not None:
-                comps = fut.result()
-            else:
-                e = self.dg.edges[self.dg._leaf_elist_eid(i)]
-                comps = self.dg._fetch_elist(e.payload_id, self.options)
+            with span("kv.wait"):
+                if fut is not None:
+                    comps = fut.result()
+                else:
+                    e = self.dg.edges[self.dg._leaf_elist_eid(i)]
+                    comps = self.dg._fetch_elist(e.payload_id, self.options)
             self._comps[i] = comps
         return comps
 
@@ -620,59 +622,70 @@ class SnapshotBatchLoader:
         segment_sum kernel reduces per-node degrees on device."""
         import jax.numpy as jnp
         from .bitmaps import np_pack
-        from ..kernels import delta_apply_fused_batched, segment_sum
+        from ..kernels import delta_apply_fused_batched, policy, segment_sum
+        from ..runtime.staging import to_device, to_host
         uni = self.gm.universe
         E, N = uni.num_edges, uni.num_nodes
-        bases = np.stack([np_pack(em) for em in edge_masks])
+        with span("host.pack"):
+            bases = np.stack([np_pack(em) for em in edge_masks])
         T, W = bases.shape
         fe = delta_apply_fused_batched(
-            jnp.asarray(bases), jnp.zeros((T, 0, W), jnp.uint32),
+            to_device(bases), jnp.zeros((T, 0, W), jnp.uint32),
             jnp.zeros((T, 0, W), jnp.uint32), impl=self.impl)
-        src = jnp.asarray(uni.edge_src[:E])
-        dst = jnp.asarray(uni.edge_dst[:E])
+        # the Pallas wrapper buckets the ids on the host and XLA reads
+        # them on the device: each gets the copy it reads, so no id array
+        # makes a round trip
+        src, dst = uni.edge_src[:E], uni.edge_dst[:E]
+        if policy.resolve(self.impl)[0] != "pallas":
+            src, dst = to_device(src), to_device(dst)
         deg = np.stack([
-            np.asarray(segment_sum(fe.live[t, :E][:, None], src, N,
-                                   impl=self.impl)
-                       + segment_sum(fe.live[t, :E][:, None], dst, N,
-                                     impl=self.impl)).reshape(-1)
+            to_host(segment_sum(fe.live[t, :E][:, None], src, N,
+                                impl=self.impl)
+                    + segment_sum(fe.live[t, :E][:, None], dst, N,
+                                  impl=self.impl)).reshape(-1)
             for t in range(T)])
         return deg.astype(np.float32), fe.live_count().astype(np.int32)
 
     def __iter__(self):
-        import jax.numpy as jnp
         from ..runtime.jax_exec import evolve_intervals_jax
+        from ..runtime.staging import to_device
         gm, bs, hz = self.gm, self.batch_size, self.label_horizon
         for i in range(len(self)):
-            window = self.times[i * bs:(i + 1) * bs]
-            intervals = [window]
-            if hz is not None:
-                intervals.append(sorted({t + hz for t in window}))
-            res = evolve_intervals_jax(gm.dg, intervals, impl=self.impl,
-                                       pool=gm.pool,
-                                       prefetch=gm.prefetcher)
-            masks = res[0]
-            node_masks = [masks[t][0] for t in window]
-            deg, num_edges = self._degrees([masks[t][1] for t in window])
-            x = np.concatenate(
-                [deg[:, :, None] * self._proj[None] * 0.1,
-                 deg[:, :, None]], axis=2)
-            # edge liveness, both directions (edge_index order)
-            live = np.stack([masks[t][1].astype(np.float32)
-                             for t in window])
-            em = np.concatenate([live, live], axis=1)
-            batch = {
-                "x": jnp.asarray(x),
-                "edge_index": jnp.asarray(self._edge_index),
-                "edge_mask": jnp.asarray(em),
-                "label_mask": jnp.asarray(
-                    np.stack(node_masks).astype(np.float32)),
-                "num_edges": jnp.asarray(num_edges),
-                "times": list(window),
-            }
-            if hz is not None:
-                fmasks = res[1]
-                fdeg, _ = self._degrees(
-                    [fmasks[t + hz][1] for t in window])
-                batch["labels"] = jnp.asarray(
-                    (fdeg > deg).astype(np.int32))
+            # the span closes before the yield: it never covers the
+            # consumer's use of the batch
+            with span("loader.batch"):
+                window = self.times[i * bs:(i + 1) * bs]
+                intervals = [window]
+                if hz is not None:
+                    intervals.append(sorted({t + hz for t in window}))
+                res = evolve_intervals_jax(gm.dg, intervals, impl=self.impl,
+                                           pool=gm.pool,
+                                           prefetch=gm.prefetcher)
+                masks = res[0]
+                node_masks = [masks[t][0] for t in window]
+                deg, num_edges = self._degrees([masks[t][1] for t in window])
+                with span("loader.assemble"):
+                    x = np.concatenate(
+                        [deg[:, :, None] * self._proj[None] * 0.1,
+                         deg[:, :, None]], axis=2)
+                    # edge liveness, both directions (edge_index order)
+                    live = np.stack([masks[t][1].astype(np.float32)
+                                     for t in window])
+                    em = np.concatenate([live, live], axis=1)
+                    batch = {
+                        "x": to_device(x),
+                        "edge_index": to_device(self._edge_index),
+                        "edge_mask": to_device(em),
+                        "label_mask": to_device(
+                            np.stack(node_masks).astype(np.float32)),
+                        "num_edges": to_device(num_edges),
+                        "times": list(window),
+                    }
+                if hz is not None:
+                    fmasks = res[1]
+                    fdeg, _ = self._degrees(
+                        [fmasks[t + hz][1] for t in window])
+                    with span("loader.assemble"):
+                        batch["labels"] = to_device(
+                            (fdeg > deg).astype(np.int32))
             yield batch

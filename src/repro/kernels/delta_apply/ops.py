@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import policy
+from ...runtime.spans import span
+from ...runtime.staging import to_host
 from .delta_apply import (delta_apply_chain_batched_pallas,
                           delta_apply_chain_pallas, delta_apply_fused_pallas)
 from .ref import (delta_apply_chain_prefix_ref, delta_apply_chain_ref,
@@ -73,17 +75,18 @@ _chain_prefix_batched_jit = jax.jit(
 def delta_apply_chain(base: jnp.ndarray, adds: jnp.ndarray, dels: jnp.ndarray,
                       *, impl: str | None = None, block_w: int = 1024,
                       interpret: bool | None = None) -> jnp.ndarray:
-    impl, interpret = policy.resolve(impl, interpret)
-    W = base.shape[0]
-    Kp = _bucket(adds.shape[0])
-    adds, dels = _pad_axis(adds, 0, Kp), _pad_axis(dels, 0, Kp)
-    if impl == "xla":
-        Wp = -(-W // _W_ALIGN) * _W_ALIGN
-        out = _chain_jit(_pad_axis(base, 0, Wp), _pad_axis(adds, 1, Wp),
-                         _pad_axis(dels, 1, Wp))
-        return out[:W]
-    return delta_apply_chain_pallas(base, adds, dels, block_w=block_w,
-                                    interpret=interpret)
+    with span("kernel.dispatch"):
+        impl, interpret = policy.resolve(impl, interpret)
+        W = base.shape[0]
+        Kp = _bucket(adds.shape[0])
+        adds, dels = _pad_axis(adds, 0, Kp), _pad_axis(dels, 0, Kp)
+        if impl == "xla":
+            Wp = -(-W // _W_ALIGN) * _W_ALIGN
+            out = _chain_jit(_pad_axis(base, 0, Wp), _pad_axis(adds, 1, Wp),
+                             _pad_axis(dels, 1, Wp))
+            return out[:W]
+        return delta_apply_chain_pallas(base, adds, dels, block_w=block_w,
+                                        interpret=interpret)
 
 
 def delta_apply_chain_batched(bases: jnp.ndarray, adds: jnp.ndarray,
@@ -99,21 +102,23 @@ def delta_apply_chain_batched(bases: jnp.ndarray, adds: jnp.ndarray,
     ``B`` sequential chain calls (the Pallas kernel carries ``B`` as a
     grid axis).
     """
-    impl, interpret = policy.resolve(impl, interpret)
-    B, K, W = adds.shape
-    Bp, Kp = _bucket(B), _bucket(K)
-    bases = _pad_axis(bases, 0, Bp)
-    adds, dels = (_pad_axis(_pad_axis(x, 1, Kp), 0, Bp) for x in (adds, dels))
-    if impl == "xla":
-        Wp = -(-W // _W_ALIGN) * _W_ALIGN
-        out = _chain_batched_jit(_pad_axis(bases, 1, Wp),
-                                 _pad_axis(adds, 2, Wp),
-                                 _pad_axis(dels, 2, Wp))
-    else:
-        out = delta_apply_chain_batched_pallas(bases, adds, dels,
-                                               block_w=block_w,
-                                               interpret=interpret)
-    return out[:B, :W]
+    with span("kernel.dispatch"):
+        impl, interpret = policy.resolve(impl, interpret)
+        B, K, W = adds.shape
+        Bp, Kp = _bucket(B), _bucket(K)
+        bases = _pad_axis(bases, 0, Bp)
+        adds, dels = (_pad_axis(_pad_axis(x, 1, Kp), 0, Bp)
+                      for x in (adds, dels))
+        if impl == "xla":
+            Wp = -(-W // _W_ALIGN) * _W_ALIGN
+            out = _chain_batched_jit(_pad_axis(bases, 1, Wp),
+                                     _pad_axis(adds, 2, Wp),
+                                     _pad_axis(dels, 2, Wp))
+        else:
+            out = delta_apply_chain_batched_pallas(bases, adds, dels,
+                                                   block_w=block_w,
+                                                   interpret=interpret)
+        return out[:B, :W]
 
 
 def delta_apply_chain_prefix(base: jnp.ndarray, adds: jnp.ndarray,
@@ -123,7 +128,8 @@ def delta_apply_chain_prefix(base: jnp.ndarray, adds: jnp.ndarray,
     over stacked intervals — every prefix is a returned snapshot bitmap,
     so (unlike :func:`delta_apply_chain`) there is no fused-kernel variant:
     each word is genuinely written once per step either way."""
-    return delta_apply_chain_prefix_ref(base, adds, dels)
+    with span("kernel.dispatch"):
+        return delta_apply_chain_prefix_ref(base, adds, dels)
 
 
 def delta_apply_chain_prefix_batched(bases: jnp.ndarray, adds: jnp.ndarray,
@@ -132,14 +138,15 @@ def delta_apply_chain_prefix_batched(bases: jnp.ndarray, adds: jnp.ndarray,
     ``[B, K, W]`` per-timepoint bitmaps for B intervals in one pass.
     Shape-bucketed and jit'd like :func:`delta_apply_chain_batched`; the
     padded identity rows repeat the final state and are sliced away."""
-    B, K, W = adds.shape
-    Wp = -(-W // _W_ALIGN) * _W_ALIGN
-    Bp, Kp = _bucket(B), _bucket(K)
-    out = _chain_prefix_batched_jit(
-        _pad_axis(_pad_axis(bases, 1, Wp), 0, Bp),
-        _pad_axis(_pad_axis(_pad_axis(adds, 2, Wp), 1, Kp), 0, Bp),
-        _pad_axis(_pad_axis(_pad_axis(dels, 2, Wp), 1, Kp), 0, Bp))
-    return out[:B, :K, :W]
+    with span("kernel.dispatch"):
+        B, K, W = adds.shape
+        Wp = -(-W // _W_ALIGN) * _W_ALIGN
+        Bp, Kp = _bucket(B), _bucket(K)
+        out = _chain_prefix_batched_jit(
+            _pad_axis(_pad_axis(bases, 1, Wp), 0, Bp),
+            _pad_axis(_pad_axis(_pad_axis(adds, 2, Wp), 1, Kp), 0, Bp),
+            _pad_axis(_pad_axis(_pad_axis(dels, 2, Wp), 1, Kp), 0, Bp))
+        return out[:B, :K, :W]
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +171,7 @@ class FusedOut(NamedTuple):
 
     def live_count(self):
         """Total live elements (int; summed over the trailing axis)."""
-        return np.asarray(self.pop).sum(axis=-1)
+        return to_host(self.pop).sum(axis=-1)
 
     def weighted_total(self):
         """Σ weights over live slots, f32 (PageRank push mass)."""
@@ -234,19 +241,21 @@ def delta_apply_fused(base: jnp.ndarray, adds: jnp.ndarray,
     ``pop``/``accw`` come back over the padded width (zero padding
     contributes nothing); ``mask`` and ``live`` are trimmed to ``W``.
     """
-    impl, interpret = policy.resolve(impl, interpret)
-    base, adds, dels, weights, W = _fused_pad(base, adds, dels, weights,
-                                              block_w)
-    if impl == "xla":
-        mask, pop, accw, live = _fused_xla_jit(
-            base, adds, dels, weights, block_w=block_w, emit_live=emit_live)
-    else:
-        outs = _fused_batched_pallas(
-            base[None], adds[None], dels[None], weights, block_w=block_w,
-            interpret=interpret, emit_live=emit_live)
-        mask, pop, accw, live = (None if x is None else x[0] for x in outs)
-    return FusedOut(mask[:W], pop, accw[:W],
-                    live[:W * 32] if live is not None else None)
+    with span("kernel.dispatch"):
+        impl, interpret = policy.resolve(impl, interpret)
+        base, adds, dels, weights, W = _fused_pad(base, adds, dels, weights,
+                                                  block_w)
+        if impl == "xla":
+            mask, pop, accw, live = _fused_xla_jit(
+                base, adds, dels, weights, block_w=block_w,
+                emit_live=emit_live)
+        else:
+            outs = _fused_batched_pallas(
+                base[None], adds[None], dels[None], weights, block_w=block_w,
+                interpret=interpret, emit_live=emit_live)
+            mask, pop, accw, live = (None if x is None else x[0] for x in outs)
+        return FusedOut(mask[:W], pop, accw[:W],
+                        live[:W * 32] if live is not None else None)
 
 
 def delta_apply_fused_batched(bases: jnp.ndarray, adds: jnp.ndarray,
@@ -259,20 +268,22 @@ def delta_apply_fused_batched(bases: jnp.ndarray, adds: jnp.ndarray,
     [B, K, W]``, one shared ``weights [W*32]`` — B chains land and emit
     their analytics partials in a single pass (B is bucketed; padded rows
     are dropped from every output)."""
-    impl, interpret = policy.resolve(impl, interpret)
-    B = bases.shape[0]
-    bases, adds, dels, weights, W = _fused_pad(bases, adds, dels, weights,
-                                               block_w)
-    Bp = _bucket(B)
-    bases = _pad_axis(bases, 0, Bp)
-    adds = _pad_axis(adds, 0, Bp)
-    dels = _pad_axis(dels, 0, Bp)
-    if impl == "xla":
-        mask, pop, accw, live = _fused_batched_xla_jit(
-            bases, adds, dels, weights, block_w=block_w, emit_live=emit_live)
-    else:
-        mask, pop, accw, live = _fused_batched_pallas(
-            bases, adds, dels, weights, block_w=block_w,
-            interpret=interpret, emit_live=emit_live)
-    return FusedOut(mask[:B, :W], pop[:B], accw[:B, :W],
-                    live[:B, :W * 32] if live is not None else None)
+    with span("kernel.dispatch"):
+        impl, interpret = policy.resolve(impl, interpret)
+        B = bases.shape[0]
+        bases, adds, dels, weights, W = _fused_pad(bases, adds, dels, weights,
+                                                   block_w)
+        Bp = _bucket(B)
+        bases = _pad_axis(bases, 0, Bp)
+        adds = _pad_axis(adds, 0, Bp)
+        dels = _pad_axis(dels, 0, Bp)
+        if impl == "xla":
+            mask, pop, accw, live = _fused_batched_xla_jit(
+                bases, adds, dels, weights, block_w=block_w,
+                emit_live=emit_live)
+        else:
+            mask, pop, accw, live = _fused_batched_pallas(
+                bases, adds, dels, weights, block_w=block_w,
+                interpret=interpret, emit_live=emit_live)
+        return FusedOut(mask[:B, :W], pop[:B], accw[:B, :W],
+                        live[:B, :W * 32] if live is not None else None)

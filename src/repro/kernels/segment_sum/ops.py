@@ -6,6 +6,8 @@ import numpy as np
 import jax.numpy as jnp
 
 from .. import policy
+from ...runtime.spans import span
+from ...runtime.staging import to_device, to_host
 from .ref import segment_sum_ref
 from .segment_sum import segment_sum_bucketed
 
@@ -49,18 +51,24 @@ def segment_sum(data: jnp.ndarray, seg_ids, num_segments: int, *,
                     precomputed ``bucket_edges`` output (static graphs).
     impl=None     → resolved by :mod:`repro.kernels.policy` (REPRO_KERNEL
                     env, else backend detection).
+
+    ``seg_ids`` may live on the host or the device; the Pallas path
+    buckets them on the host, so a host array spares it a read back.
     """
     impl, interpret = policy.resolve(impl, interpret)
-    if impl == "xla":
-        return segment_sum_ref(data, jnp.asarray(seg_ids), num_segments)
-    if impl == "pallas":
-        if buckets is None:
-            buckets = bucket_edges(np.asarray(seg_ids), num_segments, block_n)
-        out_order, local, ME = buckets
-        NB, D = local.shape[0], data.shape[-1]
-        gathered = jnp.take(data.T, jnp.asarray(out_order.reshape(-1)),
-                            axis=1).reshape(D, NB, ME).transpose(1, 0, 2)
-        out = segment_sum_bucketed(gathered, jnp.asarray(local),
-                                   block_n=block_n, interpret=interpret)
-        return out.transpose(0, 2, 1).reshape(NB * block_n, D)[:num_segments]
+    with span("kernel.dispatch"):
+        if impl == "xla":
+            return segment_sum_ref(data, to_device(seg_ids), num_segments)
+        if impl == "pallas":
+            if buckets is None:
+                buckets = bucket_edges(to_host(seg_ids), num_segments,
+                                       block_n)
+            out_order, local, ME = buckets
+            NB, D = local.shape[0], data.shape[-1]
+            gathered = jnp.take(data.T, to_device(out_order.reshape(-1)),
+                                axis=1).reshape(D, NB, ME).transpose(1, 0, 2)
+            out = segment_sum_bucketed(gathered, to_device(local),
+                                       block_n=block_n, interpret=interpret)
+            return out.transpose(0, 2, 1).reshape(NB * block_n,
+                                                  D)[:num_segments]
     raise ValueError(f"unknown impl {impl!r}")

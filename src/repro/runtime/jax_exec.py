@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .staging import DeviceStager, stream_chunk_k
+from .spans import span
+from .staging import DeviceStager, stream_chunk_k, to_device, to_host
 from ..core import bitmaps as bmod
 from ..core import planir
 from ..core.deltagraph import DeltaGraph, Plan
@@ -139,8 +140,9 @@ def _stack_bitmaps(chain_idx: list[np.ndarray], U: int) -> jnp.ndarray:
     W = bmod.num_words(U)
     if not chain_idx:
         return jnp.zeros((0, W), jnp.uint32)
-    rows = [np.asarray(bmod.np_from_indices(ix, U)) for ix in chain_idx]
-    return jnp.asarray(np.stack(rows))
+    with span("host.pack"):
+        rows = np.stack([bmod.np_from_indices(ix, U) for ix in chain_idx])
+    return to_device(rows)
 
 
 def execute_singlepoint_jax(dg: DeltaGraph, t: int, *, impl: str | None = None,
@@ -301,12 +303,13 @@ def _make_payload_resolver(dg: DeltaGraph, ir: Plan, prefetch):
         fk = (kind, pid)
         if fk not in payloads:
             fut = futs.pop(fk, None)
-            if fut is not None:
-                payloads[fk] = fut.result()   # decoded in the worker
-            else:
-                payloads[fk] = (dg._fetch_delta(pid, NO_ATTRS)
-                                if kind == "delta"
-                                else dg._fetch_elist(pid, NO_ATTRS))
+            with span("kv.wait"):
+                if fut is not None:
+                    payloads[fk] = fut.result()   # decoded in the worker
+                else:
+                    payloads[fk] = (dg._fetch_delta(pid, NO_ATTRS)
+                                    if kind == "delta"
+                                    else dg._fetch_elist(pid, NO_ATTRS))
         return payloads[fk]
 
     return get_payload
@@ -343,23 +346,24 @@ def _apply_chains_streamed(bases_n, bases_e, chains, U_n: int, U_e: int, *,
         dn = np.zeros((B, k, W_n), np.uint32)
         ae = np.zeros((B, k, W_e), np.uint32)
         de = np.zeros((B, k, W_e), np.uint32)
-        for i, chain in enumerate(chains):
-            for j in range(lo, min(hi, len(chain))):
-                na, nd, ea, ed = chain[j]
-                an[i, j - lo] = bmod.np_from_indices(na, U_n)
-                dn[i, j - lo] = bmod.np_from_indices(nd, U_n)
-                ae[i, j - lo] = bmod.np_from_indices(ea, U_e)
-                de[i, j - lo] = bmod.np_from_indices(ed, U_e)
+        with span("host.pack"):
+            for i, chain in enumerate(chains):
+                for j in range(lo, min(hi, len(chain))):
+                    na, nd, ea, ed = chain[j]
+                    an[i, j - lo] = bmod.np_from_indices(na, U_n)
+                    dn[i, j - lo] = bmod.np_from_indices(nd, U_n)
+                    ae[i, j - lo] = bmod.np_from_indices(ea, U_e)
+                    de[i, j - lo] = bmod.np_from_indices(ed, U_e)
         return an, dn, ae, de
 
     ck = stream_chunk_k()
     if ck < 1 or K <= ck:
         an, dn, ae, de = build(0, K)
         out_n = delta_apply_chain_batched(
-            jnp.asarray(bases_n), jnp.asarray(an), jnp.asarray(dn), impl=impl)
+            to_device(bases_n), to_device(an), to_device(dn), impl=impl)
         out_e = delta_apply_chain_batched(
-            jnp.asarray(bases_e), jnp.asarray(ae), jnp.asarray(de), impl=impl)
-        return np.asarray(out_n), np.asarray(out_e)
+            to_device(bases_e), to_device(ae), to_device(de), impl=impl)
+        return to_host(out_n), to_host(out_e)
 
     if stager is None:
         stager = DeviceStager(prefetcher=prefetch)
@@ -373,8 +377,8 @@ def _apply_chains_streamed(bases_n, bases_e, chains, U_n: int, U_e: int, *,
 
     bn, be = stager.stream(
         nch, lambda i: build(i * ck, min((i + 1) * ck, K)), apply_chunk,
-        (jnp.asarray(bases_n), jnp.asarray(bases_e)))
-    return np.asarray(bn), np.asarray(be)
+        (to_device(bases_n), to_device(bases_e)))
+    return to_host(bn), to_host(be)
 
 
 def execute_ir_jax(dg: DeltaGraph, ir: Plan, *, impl: str | None = None,
@@ -455,8 +459,9 @@ def execute_ir_jax(dg: DeltaGraph, ir: Plan, *, impl: str | None = None,
                 segments.append((pnid, seg))
         if not segments:
             break
-        chains = [[_node_pair(dg, byid[s].op, get_payload) for s in seg]
-                  for _, seg in segments]
+        with span("slice.quads"):
+            chains = [[_node_pair(dg, byid[s].op, get_payload) for s in seg]
+                      for _, seg in segments]
         bases_n = np.stack([vals[p][0] for p, _ in segments])
         bases_e = np.stack([vals[p][1] for p, _ in segments])
         out_n, out_e = _apply_chains_streamed(
@@ -469,12 +474,13 @@ def execute_ir_jax(dg: DeltaGraph, ir: Plan, *, impl: str | None = None,
             expand(end)
 
     out: dict[Any, tuple[np.ndarray, np.ndarray]] = {}
-    for tgt, nid in ir.targets.items():
-        nm = bmod.np_unpack(vals[nid][0], U_n)
-        em = bmod.np_unpack(vals[nid][1], U_e)
-        nm &= ~dg.universe.node_transient[:U_n]
-        em &= ~dg.universe.edge_transient[:U_e]
-        out[tgt] = (nm, em)
+    with span("host.unpack"):
+        for tgt, nid in ir.targets.items():
+            nm = bmod.np_unpack(vals[nid][0], U_n)
+            em = bmod.np_unpack(vals[nid][1], U_e)
+            nm &= ~dg.universe.node_transient[:U_n]
+            em &= ~dg.universe.edge_transient[:U_e]
+            out[tgt] = (nm, em)
     return out
 
 
@@ -538,8 +544,9 @@ def evolve_intervals_jax(dg: DeltaGraph, intervals, *, impl: str | None = None,
     slicer = IntervalSlicer(dg, NO_ATTRS, prefetcher=prefetch)
     for iv in ivs:
         slicer.prefetch_interval(iv[0], iv[-1])
-    quads = [[slicer.quad(lo, hi) for lo, hi in zip(iv, iv[1:])]
-             for iv in ivs]
+    with span("slice.quads"):
+        quads = [[slicer.quad(lo, hi) for lo, hi in zip(iv, iv[1:])]
+                 for iv in ivs]
 
     # 3. vmapped prefix sweep (zero-padded rows are identity steps)
     B = len(ivs)
@@ -548,8 +555,11 @@ def evolve_intervals_jax(dg: DeltaGraph, intervals, *, impl: str | None = None,
         {iv[0]: start_masks[iv[0]]} for iv in ivs]
     if Kmax == 0:
         return out
-    bases_n = np.stack([bmod.np_pack(start_masks[iv[0]][0]) for iv in ivs])
-    bases_e = np.stack([bmod.np_pack(start_masks[iv[0]][1]) for iv in ivs])
+    with span("host.pack"):
+        bases_n = np.stack([bmod.np_pack(start_masks[iv[0]][0])
+                            for iv in ivs])
+        bases_e = np.stack([bmod.np_pack(start_masks[iv[0]][1])
+                            for iv in ivs])
 
     def build(lo: int, hi: int):
         k = hi - lo
@@ -557,22 +567,23 @@ def evolve_intervals_jax(dg: DeltaGraph, intervals, *, impl: str | None = None,
         dn = np.zeros((B, k, W_n), np.uint32)
         ae = np.zeros((B, k, W_e), np.uint32)
         de = np.zeros((B, k, W_e), np.uint32)
-        for b, qs in enumerate(quads):
-            for j in range(lo, min(hi, len(qs))):
-                q = qs[j]
-                an[b, j - lo] = bmod.np_from_indices(q.node_add, U_n)
-                dn[b, j - lo] = bmod.np_from_indices(q.node_del, U_n)
-                ae[b, j - lo] = bmod.np_from_indices(q.edge_add, U_e)
-                de[b, j - lo] = bmod.np_from_indices(q.edge_del, U_e)
+        with span("host.pack"):
+            for b, qs in enumerate(quads):
+                for j in range(lo, min(hi, len(qs))):
+                    q = qs[j]
+                    an[b, j - lo] = bmod.np_from_indices(q.node_add, U_n)
+                    dn[b, j - lo] = bmod.np_from_indices(q.node_del, U_n)
+                    ae[b, j - lo] = bmod.np_from_indices(q.edge_add, U_e)
+                    de[b, j - lo] = bmod.np_from_indices(q.edge_del, U_e)
         return an, dn, ae, de
 
     ck = stream_chunk_k()
     if ck < 1 or Kmax <= ck:
         an, dn, ae, de = build(0, Kmax)
-        pref_n = np.asarray(delta_apply_chain_prefix_batched(
-            jnp.asarray(bases_n), jnp.asarray(an), jnp.asarray(dn)))
-        pref_e = np.asarray(delta_apply_chain_prefix_batched(
-            jnp.asarray(bases_e), jnp.asarray(ae), jnp.asarray(de)))
+        pref_n = to_host(delta_apply_chain_prefix_batched(
+            to_device(bases_n), to_device(an), to_device(dn)))
+        pref_e = to_host(delta_apply_chain_prefix_batched(
+            to_device(bases_e), to_device(ae), to_device(de)))
     else:
         # streamed prefix sweep: each chunk's last prefix seeds the next
         # chunk's base, so chunked prefixes concatenate bit-identically
@@ -591,16 +602,17 @@ def evolve_intervals_jax(dg: DeltaGraph, intervals, *, impl: str | None = None,
 
         stager.stream(nch, lambda i: build(i * ck, min((i + 1) * ck, Kmax)),
                       apply_chunk,
-                      (jnp.asarray(bases_n), jnp.asarray(bases_e)))
-        pref_n = np.concatenate([np.asarray(p[0]) for p in parts], axis=1)
-        pref_e = np.concatenate([np.asarray(p[1]) for p in parts], axis=1)
-    for b, iv in enumerate(ivs):
-        for j, t in enumerate(iv[1:]):
-            nm = bmod.np_unpack(pref_n[b, j], U_n)
-            em = bmod.np_unpack(pref_e[b, j], U_e)
-            nm &= ~dg.universe.node_transient[:U_n]
-            em &= ~dg.universe.edge_transient[:U_e]
-            out[b][t] = (nm, em)
+                      (to_device(bases_n), to_device(bases_e)))
+        pref_n = np.concatenate([to_host(p[0]) for p in parts], axis=1)
+        pref_e = np.concatenate([to_host(p[1]) for p in parts], axis=1)
+    with span("host.unpack"):
+        for b, iv in enumerate(ivs):
+            for j, t in enumerate(iv[1:]):
+                nm = bmod.np_unpack(pref_n[b, j], U_n)
+                em = bmod.np_unpack(pref_e[b, j], U_e)
+                nm &= ~dg.universe.node_transient[:U_n]
+                em &= ~dg.universe.edge_transient[:U_e]
+                out[b][t] = (nm, em)
     return out
 
 

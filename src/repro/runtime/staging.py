@@ -14,6 +14,10 @@ resident staging memory to two chunks.
 Chunked application is exact: the delta chain is a left fold of bitwise
 steps, so landing it ``chunk_k`` rows at a time produces bit-identical
 masks (pinned by ``tests/test_device_pipeline.py``).
+
+Every host↔device copy of the retrieval path goes through
+:func:`to_device` and :func:`to_host`, which run each copy under an
+``h2d.put`` / ``d2h.copy`` span carrying the ``bytes`` it moves.
 """
 from __future__ import annotations
 
@@ -21,7 +25,36 @@ import os
 from collections import deque
 from typing import Any, Callable, Sequence
 
+import numpy as np
+
 import jax
+import jax.numpy as jnp
+
+from .spans import span
+
+
+def to_device(a) -> jax.Array:
+    """``jnp.asarray(a)`` under an ``h2d.put`` span carrying the bytes the
+    device array holds; a device array is returned as it is."""
+    if isinstance(a, jax.Array):
+        return a
+    a = np.asarray(a)
+    # a span's stats are fixed when it opens: the bytes the device array
+    # will hold, in the dtype JAX gives it
+    n = a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+    with span("h2d.put", bytes=n):
+        return jnp.asarray(a)
+
+
+def to_host(x) -> np.ndarray:
+    """``np.asarray(x)``; a device array is read back under a ``d2h.copy``
+    span carrying its bytes (the read waits for the program producing
+    it).  Every call on a device array counts as a copy, so the path
+    reads each array back once and hands the host copy on."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    with span("d2h.copy", bytes=x.nbytes):
+        return np.asarray(x)
 
 
 def stream_chunk_k(default: int = 8) -> int:
@@ -36,9 +69,9 @@ def stream_chunk_k(default: int = 8) -> int:
 class DeviceStager:
     """Pipelines ``build → put → apply`` over a chunk sequence.
 
-    ``put_fn`` is injectable so tests can substitute an instrumented fake
-    and assert on :attr:`events` — the recorded call order proves chunk
-    *i+1* is staged before chunk *i*'s apply result is consumed.
+    ``put_fn`` (default :func:`to_device`, which spans each copy) is
+    injectable, so tests can record the order of builds, puts and applies
+    in their own closures.
     """
 
     def __init__(self, depth: int = 2,
@@ -47,19 +80,8 @@ class DeviceStager:
         if depth < 1:
             raise ValueError("depth must be >= 1")
         self.depth = int(depth)
-        self.put_fn = put_fn if put_fn is not None else jax.device_put
+        self.put_fn = put_fn if put_fn is not None else to_device
         self.prefetcher = prefetcher
-        self.events: list[tuple[str, int]] = []   # ("build"|"put"|"apply", i)
-
-    def _put(self, host_chunk: Sequence[Any], idx: int) -> tuple:
-        dev = tuple(self.put_fn(h) for h in host_chunk)
-        self.events.append(("put", idx))
-        return dev
-
-    def _build(self, build_chunk, idx: int):
-        host = build_chunk(idx)
-        self.events.append(("build", idx))
-        return host
 
     def stream(self, num_chunks: int, build_chunk: Callable[[int], Sequence],
                apply_chunk: Callable[[Any, tuple], Any], carry: Any) -> Any:
@@ -81,27 +103,25 @@ class DeviceStager:
 
         def kick(i: int) -> None:
             nonlocal ahead
-            ahead = ((i, self.prefetcher.submit_fn(
-                self._build, build_chunk, i))
-                if self.prefetcher is not None and i < num_chunks else None)
+            ahead = ((i, self.prefetcher.submit_fn(build_chunk, i))
+                     if self.prefetcher is not None and i < num_chunks
+                     else None)
 
         def obtain(i: int):
             nonlocal ahead
             if ahead is not None and ahead[0] == i:
                 host = ahead[1].result()
             else:
-                host = self._build(build_chunk, i)
+                host = build_chunk(i)
             kick(i + 1)
             return host
 
         kick(0)
-        staged: deque[tuple[int, tuple]] = deque()
+        staged: deque[tuple] = deque()
         next_i = 0
         while staged or next_i < num_chunks:
             while next_i < num_chunks and len(staged) < self.depth:
-                staged.append((next_i, self._put(obtain(next_i), next_i)))
+                staged.append(tuple(self.put_fn(h) for h in obtain(next_i)))
                 next_i += 1
-            i, dev = staged.popleft()
-            carry = apply_chunk(carry, dev)
-            self.events.append(("apply", i))
+            carry = apply_chunk(carry, staged.popleft())
         return carry

@@ -24,41 +24,61 @@ from repro.runtime.staging import DeviceStager, stream_chunk_k
 # ---------------------------------------------------------------------------
 
 
+def _logged_stager(depth: int, log: list) -> DeviceStager:
+    """A stager whose puts record ``("put", chunk)`` in ``log``; each test
+    chunk holds one array filled with its index."""
+    def put(x):
+        log.append(("put", int(x[0])))
+        return x
+    return DeviceStager(depth=depth, put_fn=put)
+
+
 def test_stager_overlap_order():
     """With depth=2, chunk i+1 is built and put before chunk i's apply is
-    issued — the double-buffering contract, visible in the event log."""
-    st = DeviceStager(depth=2, put_fn=lambda x: x)
+    issued — the double-buffering contract, visible in the call log."""
+    log = []
+    st = _logged_stager(2, log)
     chunks = [(np.full(4, i),) for i in range(5)]
     seen = []
 
+    def build(i):
+        log.append(("build", i))
+        return chunks[i]
+
     def apply(carry, dev):
         seen.append(int(dev[0][0]))
+        log.append(("apply", int(dev[0][0])))
         return carry + dev[0].sum()
 
-    out = st.stream(5, lambda i: chunks[i], apply, 0)
+    out = st.stream(5, build, apply, 0)
     assert out == sum(np.full(4, i).sum() for i in range(5))
     assert seen == [0, 1, 2, 3, 4]
-    puts = [i for kind, i in st.events if kind == "put"]
-    applies = [i for kind, i in st.events if kind == "apply"]
+    puts = [i for kind, i in log if kind == "put"]
+    applies = [i for kind, i in log if kind == "apply"]
     assert puts == [0, 1, 2, 3, 4] and applies == [0, 1, 2, 3, 4]
     # put(1) precedes apply(0): one chunk always staged ahead
-    assert st.events.index(("put", 1)) < st.events.index(("apply", 0))
-    assert st.events.index(("put", 2)) < st.events.index(("apply", 1))
+    assert log.index(("put", 1)) < log.index(("apply", 0))
+    assert log.index(("put", 2)) < log.index(("apply", 1))
+    # every chunk is built before it is put
+    for i in range(5):
+        assert log.index(("build", i)) < log.index(("put", i))
 
 
 def test_stager_depth_bound():
     """Never more than `depth` puts ahead of the apply cursor — resident
     staging memory is bounded."""
-    st = DeviceStager(depth=3, put_fn=lambda x: x)
+    log = []
+    st = _logged_stager(3, log)
     ahead = []
 
     def apply(carry, dev):
-        puts = sum(1 for k, _ in st.events if k == "put")
-        applies = sum(1 for k, _ in st.events if k == "apply")
+        puts = sum(1 for k, _ in log if k == "put")
+        applies = sum(1 for k, _ in log if k == "apply")
         ahead.append(puts - applies)
+        log.append(("apply", int(dev[0][0])))
         return carry
 
-    st.stream(8, lambda i: (np.zeros(1),), apply, None)
+    st.stream(8, lambda i: (np.full(1, i),), apply, None)
     assert max(ahead) <= 3
 
 
@@ -115,9 +135,11 @@ def test_streamed_ir_bit_identical(monkeypatch):
     ir = gm.dg.plan_multipoint(times, NO_ATTRS, True)
     mono = execute_ir_jax(gm.dg, ir, pool=gm.pool)
     monkeypatch.setenv("REPRO_STREAM_CHUNK", "1")   # force max chunking
-    stager = DeviceStager()
+    puts = []
+    stager = DeviceStager(put_fn=lambda h: puts.append(h.shape) or
+                          jnp.asarray(h))
     streamed = execute_ir_jax(gm.dg, ir, pool=gm.pool, stager=stager)
-    assert any(k == "apply" for k, _ in stager.events)  # streaming engaged
+    assert puts                                        # streaming engaged
     for t in times:
         assert np.array_equal(mono[t][0], streamed[t][0]), t
         assert np.array_equal(mono[t][1], streamed[t][1]), t
