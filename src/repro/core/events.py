@@ -25,7 +25,7 @@ QueryManager id-translation role).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Iterable, Mapping, Sequence
+from typing import Any, Callable, Iterable, Mapping, Sequence
 
 import numpy as np
 
@@ -129,7 +129,9 @@ class GraphUniverse:
         self.node_attr_cols: dict[str, int] = {}
         self.edge_attr_cols: dict[str, int] = {}
         self.strings = InternTable()
-        self._finalized: dict[str, np.ndarray] = {}
+        # finalized arrays by name, and memo() entries; every
+        # registration clears it
+        self._finalized: dict[Any, Any] = {}
 
     # -- registration -------------------------------------------------------
     def node_slot(self, ext_id: Any, create: bool = False,
@@ -186,6 +188,15 @@ class GraphUniverse:
     @property
     def num_edge_attrs(self) -> int:
         return len(self.edge_attr_cols)
+
+    def memo(self, key: Any, build: Callable[[], Any]) -> Any:
+        """``build()``, kept under ``key`` (a tuple) until a registration
+        changes the universe: for tables derived from its registries
+        alone, such as ``segment_sum``'s bucketing of the edge endpoints."""
+        value = self._finalized.get(key)
+        if value is None:
+            value = self._finalized[key] = build()
+        return value
 
     # -- finalized arrays ----------------------------------------------------
     def _arr(self, name: str, src: list, dtype) -> np.ndarray:

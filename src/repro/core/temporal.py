@@ -623,6 +623,7 @@ class SnapshotBatchLoader:
         import jax.numpy as jnp
         from .bitmaps import np_pack
         from ..kernels import delta_apply_fused_batched, policy, segment_sum
+        from ..runtime.jax_exec import edge_buckets
         from ..runtime.staging import to_device, to_host
         uni = self.gm.universe
         E, N = uni.num_edges, uni.num_nodes
@@ -632,17 +633,20 @@ class SnapshotBatchLoader:
         fe = delta_apply_fused_batched(
             to_device(bases), jnp.zeros((T, 0, W), jnp.uint32),
             jnp.zeros((T, 0, W), jnp.uint32), impl=self.impl)
-        # the Pallas wrapper buckets the ids on the host and XLA reads
-        # them on the device: each gets the copy it reads, so no id array
-        # makes a round trip
+        # the Pallas wrapper takes the universe's bucket tables, built once
+        # and kept on the device; XLA reads the ids on the device
         src, dst = uni.edge_src[:E], uni.edge_dst[:E]
-        if policy.resolve(self.impl)[0] != "pallas":
+        if policy.resolve(self.impl)[0] == "pallas":
+            b_src, b_dst = edge_buckets(uni)
+        else:
             src, dst = to_device(src), to_device(dst)
+            b_src = b_dst = None
         deg = np.stack([
             to_host(segment_sum(fe.live[t, :E][:, None], src, N,
-                                impl=self.impl)
+                                impl=self.impl, buckets=b_src)
                     + segment_sum(fe.live[t, :E][:, None], dst, N,
-                                  impl=self.impl)).reshape(-1)
+                                  impl=self.impl, buckets=b_dst)
+                    ).reshape(-1)
             for t in range(T)])
         return deg.astype(np.float32), fe.live_count().astype(np.int32)
 

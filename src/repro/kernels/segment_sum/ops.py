@@ -48,7 +48,10 @@ def segment_sum(data: jnp.ndarray, seg_ids, num_segments: int, *,
 
     impl='xla'    → jax.ops.segment_sum (scatter; lowering/roofline path)
     impl='pallas' → bucketed one-hot-matmul kernel; ``buckets`` may carry
-                    precomputed ``bucket_edges`` output (static graphs).
+                    precomputed ``bucket_edges`` output (static graphs),
+                    its tables on the host or already on the device
+                    (``runtime.jax_exec.edge_buckets`` keeps a universe's
+                    there); ``seg_ids`` is then not read.
     impl=None     → resolved by :mod:`repro.kernels.policy` (REPRO_KERNEL
                     env, else backend detection).
 

@@ -34,10 +34,10 @@ from ..core import planir
 from ..core.deltagraph import DeltaGraph, Plan
 from ..core.events import (EV_DEL_EDGE, EV_DEL_NODE, EV_NEW_EDGE, EV_NEW_NODE)
 from ..core.query import NO_ATTRS
-from ..kernels import (FusedOut, delta_apply_chain,
+from ..kernels import (FusedOut, bucket_edges, delta_apply_chain,
                        delta_apply_chain_batched,
                        delta_apply_chain_prefix_batched, delta_apply_fused,
-                       segment_sum)
+                       policy, segment_sum)
 from ..storage import columnar as col
 
 
@@ -170,6 +170,30 @@ def execute_singlepoint_jax(dg: DeltaGraph, t: int, *, impl: str | None = None,
 # ---------------------------------------------------------------------------
 
 
+def edge_buckets(uni, block_n: int = 128) -> tuple[tuple, tuple]:
+    """``segment_sum``'s Pallas bucket tables for the universe's edge
+    sources and destinations, as its ``buckets=`` takes them, on the device.
+
+    The tables are a pure function of the edge endpoints, ``num_nodes`` and
+    ``block_n``, and the universe only appends, so they are built once per
+    universe shape (one ``kernel.bucket`` span a side) and kept in the
+    universe's memo, which the next append clears.  ``block_n`` must be
+    the one the ``segment_sum`` calls pass.
+    """
+    N, E = uni.num_nodes, uni.num_edges
+
+    def build():
+        tables = []
+        for ids in (uni.edge_src[:E], uni.edge_dst[:E]):
+            with span("kernel.bucket"):
+                order, local, ME = bucket_edges(ids, N, block_n)
+                tables.append((to_device(order.reshape(-1)),
+                               to_device(local), ME))
+        return tuple(tables)
+
+    return uni.memo(("segment_sum.buckets", N, E, block_n), build)
+
+
 class SnapshotAnalytics:
     """Push-style analytics emitted by the fused delta-apply kernel: the
     node/edge :class:`FusedOut` partials from the same pass that landed the
@@ -196,10 +220,14 @@ class SnapshotAnalytics:
         uni = self._dg.universe
         E, N = uni.num_edges, uni.num_nodes
         live = self.edge.live[:E][:, None]
-        src = jnp.asarray(uni.edge_src[:E])
-        dst = jnp.asarray(uni.edge_dst[:E])
-        deg = (segment_sum(live, src, N, impl=impl)
-               + segment_sum(live, dst, N, impl=impl))
+        src, dst = uni.edge_src[:E], uni.edge_dst[:E]
+        if policy.resolve(impl)[0] == "pallas":
+            b_src, b_dst = edge_buckets(uni)
+        else:
+            src, dst = jnp.asarray(src), jnp.asarray(dst)
+            b_src = b_dst = None
+        deg = (segment_sum(live, src, N, impl=impl, buckets=b_src)
+               + segment_sum(live, dst, N, impl=impl, buckets=b_dst))
         return np.asarray(deg).reshape(-1)
 
 

@@ -22,6 +22,9 @@ trace readers use to pick the program's spans out of a trace:
   the program that produces the array, so it includes that device time);
 * ``kernel.dispatch``: host time of a kernel wrapper (padding, host-side
   preprocessing, dispatch);
+* ``kernel.bucket``: building and uploading a universe's ``segment_sum``
+  bucket tables (``runtime.jax_exec.edge_buckets``), once per universe
+  shape, outside ``kernel.dispatch``;
 * ``loader.batch``: one batch of ``SnapshotBatchLoader``, closed before
   the batch is yielded;
 * ``loader.assemble``: a batch's feature, mask and label arrays.
@@ -32,7 +35,8 @@ from jax.profiler import TraceAnnotation
 
 NAMES = ("retrieve.plan", "slice.quads", "codec.decode", "kv.wait",
          "host.pack", "host.unpack", "h2d.put", "d2h.copy",
-         "kernel.dispatch", "loader.batch", "loader.assemble")
+         "kernel.dispatch", "kernel.bucket", "loader.batch",
+         "loader.assemble")
 
 
 def span(name: str, **stats) -> TraceAnnotation:

@@ -2,11 +2,12 @@
 that carry their bytes (`runtime/staging.py`).
 
 One `SnapshotBatchLoader` batch runs under a profiler trace on the CPU;
-the trace must hold every program span, `loader.batch` must close before
-the batch reaches its consumer, and the copy spans must carry exactly the
-bytes the batch's copies move.  A source scan pins `NAMES` to the span
-literals in `src/`, so a renamed span cannot drop out of a trace reader
-that selects by `NAMES`.
+`loader.batch` must close before the batch reaches its consumer, and the
+copy spans must carry exactly the bytes the batch's copies move.  Two
+Pallas batches on a fresh universe run under another trace, which must
+hold every program span, `kernel.bucket` among them.  A source scan pins
+`NAMES` to the span literals in `src/`, so a renamed span cannot drop out
+of a trace reader that selects by `NAMES`.
 """
 import glob
 import re
@@ -74,10 +75,51 @@ def traced_batch(tmp_path_factory):
             "E": gm.universe.num_edges}
 
 
-def test_every_span_is_in_the_trace(traced_batch):
-    ev = traced_batch["events"]
+@pytest.fixture(scope="module")
+def traced_pallas_batches(tmp_path_factory):
+    """Two Pallas batches from two loaders under a trace, on a universe
+    no loader has seen, so its bucket tables are built inside the trace."""
+    gm, loader = _loader("pallas")
+    warm = next(iter(loader))                 # compiles outside the trace
+    jax.block_until_ready(warm["x"])
+    gm.close()
+    gm, loader = _loader("pallas")            # same shapes, new universe
+    trace_dir = tmp_path_factory.mktemp("trace_pallas")
+    with jax.profiler.trace(str(trace_dir)):
+        for _ in range(2):
+            jax.block_until_ready(next(iter(loader))["x"])
+            loader = SnapshotBatchLoader(gm, loader.times, batch_size=4,
+                                         label_horizon=loader.label_horizon,
+                                         d_in=8, impl="pallas")
+    gm.close()
+    return {"events": _host_events(trace_dir), "universe": gm.universe}
+
+
+def test_every_span_is_in_the_trace(traced_pallas_batches):
+    ev = traced_pallas_batches["events"]
     missing = [n for n in spans.NAMES if not ev.get(n)]
     assert not missing
+
+
+def test_bucket_tables_build_once_outside_dispatch(traced_pallas_batches):
+    """One `kernel.bucket` a side, in the first batch only, never inside
+    `kernel.dispatch`; it holds the tables' four uploads."""
+    from repro.kernels import bucket_edges
+    ev = traced_pallas_batches["events"]
+    first, _ = sorted(ev["loader.batch"], key=lambda e: e[1])
+    builds = ev["kernel.bucket"]
+    assert len(builds) == 2
+    for line, a, b, _ in builds:
+        assert line == first[0] and first[1] <= a and b <= first[2]
+        for dline, da, db, _ in ev["kernel.dispatch"]:
+            assert dline != line or db <= a or b <= da
+    inside = [s["bytes"] for line, a, b, s in ev["h2d.put"]
+              if any(line == bl and ba <= a and b <= bb
+                     for bl, ba, bb, _ in builds)]
+    uni = traced_pallas_batches["universe"]
+    tables = [4 * bucket_edges(ids, uni.num_nodes, 128)[1].size
+              for ids in (uni.edge_src, uni.edge_dst)]
+    assert sorted(inside) == sorted(2 * tables)
 
 
 def test_loader_batch_closes_before_the_yield(traced_batch):
