@@ -1,10 +1,12 @@
-"""Seeded historical graphs: a frozen copy of the program's generator.
+"""Seeded historical graphs.
 
-``churn_network`` draws exactly the random numbers that
-``repro.data.generators`` draws, in the same order, and records the same
-events, so the same seed gives a byte-identical trace
-(``bench/tests/test_history.py``).  It is copied here so that a change
-to the program cannot change the data a cell runs on.
+Each generator is a file of its own under ``bench/generators/``, a
+frozen copy of the generator of that name in ``repro.data.generators``:
+it draws exactly the random numbers the original draws, in the same
+order, and records the same events through :class:`_Recorder`, so the
+same seed gives a byte-identical trace (``bench/tests/test_history.py``).
+They are copied so that a change to the program cannot change the data
+a cell runs on.
 
 A history is kept as plain arrays (:class:`History`), built without the
 program.  :func:`to_program` hands it to the program's own universe and
@@ -14,8 +16,11 @@ event list, the form ``GraphManager`` indexes; the plain reference
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
 import numpy as np
+
+from . import parts
 
 ATTR_NAMES = [f"attr{i}" for i in range(10)]
 
@@ -165,70 +170,13 @@ class _LiveOrder:
         return self._keys[pos]
 
 
-def churn_network(n_initial_edges: int = 500, n_events: int = 4000,
-                  seed: int = 0, p_delete: float = 0.4,
-                  p_attr_update: float = 0.1, p_transient: float = 0.02,
-                  n_attrs: int = 2, superlinear: bool = False) -> History:
-    """The paper's Dataset 2/3 analogue: a starting graph, then edge
-    additions and deletions, attribute updates and transient edges."""
-    rng = np.random.default_rng(seed)
-    b = _Recorder()
-    n_nodes = max(8, n_initial_edges // 3)
-    for n in range(n_nodes):
-        b.add_node(n, 0, attrs={ATTR_NAMES[j]: float(rng.random())
-                                for j in range(n_attrs)})
-    live: dict[tuple[int, int], tuple[int, int]] = {}
-    order = _LiveOrder(n_initial_edges + n_events)
-    eid = 0
-    for _ in range(n_initial_edges):
-        u, v = rng.integers(0, n_nodes, 2)
-        if u == v or (int(u), int(v)) in live or (int(v), int(u)) in live:
-            continue
-        key = (int(u), int(v))
-        live[key] = (b.add_edge(*key, 1, edge_id=("e", eid)),
-                     order.append(key))
-        eid += 1
-    times = _times(rng, n_events, superlinear) + 2
-    i = 0
-    emitted = 0
-    while emitted < n_events:
-        t = int(times[min(i, len(times) - 1)])
-        i += 1
-        r = rng.random()
-        if r < p_transient:
-            u, v = rng.integers(0, n_nodes, 2)
-            b.transient_edge(int(u), int(v), t)
-            emitted += 1
-        elif r < p_transient + p_attr_update:
-            n = int(rng.integers(0, n_nodes))
-            b.set_node_attr(n, ATTR_NAMES[int(rng.integers(0, n_attrs))],
-                            float(rng.random()), t)
-            emitted += 1
-        elif live and r < p_transient + p_attr_update + p_delete:
-            slot, pos = live.pop(order.nth(int(rng.integers(0, len(live)))))
-            order.remove(pos)
-            b.delete_edge_slot(slot, t)
-            emitted += 1
-        else:
-            u, v = rng.integers(0, n_nodes, 2)
-            if u == v or (int(u), int(v)) in live or (int(v), int(u)) in live:
-                continue
-            key = (int(u), int(v))
-            live[key] = (b.add_edge(*key, t, edge_id=("e", eid)),
-                         order.append(key))
-            eid += 1
-            emitted += 1
-    return b.finalize()
-
-
-GENERATORS = {"churn_network": churn_network}
-
-
-def generate(spec: dict, seed: int) -> History:
-    """The history a configuration names: ``spec`` is its ``history``
-    entry, ``{"generator": name, **parameters}``."""
+def generator(spec: dict) -> Callable[[int], History]:
+    """The generator a configuration's ``history`` entry names,
+    ``{"generator": name, **parameters}``: ``bench/generators/<name>.py``,
+    found before anything is generated.  Call it with the seed."""
+    mod = parts.find("generators", spec["generator"])
     params = {k: v for k, v in spec.items() if k != "generator"}
-    return GENERATORS[spec["generator"]](seed=seed, **params)
+    return lambda seed: mod.generate(seed, **params)
 
 
 def with_capacity(h: History, spec: dict) -> History:
@@ -259,7 +207,7 @@ def with_capacity(h: History, spec: dict) -> History:
 
 def build(config: dict, seed: int) -> History:
     """The history of a configuration file, at its capacity."""
-    return with_capacity(generate(config["history"], seed),
+    return with_capacity(generator(config["history"])(seed),
                          config["universe"])
 
 

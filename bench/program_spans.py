@@ -38,7 +38,7 @@ from bench.trace import DEVICE_LINES, gaps
 
 ROOT = Path(__file__).resolve().parents[1]
 TRACE_DIR = ROOT / "bench" / ".run" / "trace"
-# the harness's own spans (bench/load.py); the window span comes first
+# the loader path's harness spans (bench/paths/loader.py SPANS), the window first
 HARNESS = ("loader.window", "loader.next", "consumer.step")
 OUTSIDE = "outside harness spans"
 

@@ -22,10 +22,12 @@ configuration or a metric by adding files and entries only:
 * a configuration is the file its ``configs`` entry names (history
   generator and parameters, store capacity, index, store, caches,
   guarantees);
-* a traffic mix is ``bench/traffic/<traffic>.json``, read by the one
-  general generator, ``bench/load.py``, through its ``path``;
-* a metric is ``bench/metrics/<name>.py``, whose ``read(run)`` returns
-  the number or ``None`` when it finds nothing to read;
+* a traffic mix is ``bench/traffic/<traffic>.json``, which names its
+  ``path`` and the parameters of its load (``bench/load.py``);
+* the generator, the store kind, the path and each metric are files of
+  their own, ``bench/{generators,stores,paths,metrics}/<name>.py``,
+  found by ``bench/parts.py``; a name with no file fails before anything
+  is generated or compiled;
 * limits of the numbers compared live in the traffic file's ``limits``.
 """
 from __future__ import annotations
@@ -38,7 +40,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -51,6 +52,8 @@ RUN_DIR = BENCH / ".run"
 if sys.path and Path(sys.path[0]).resolve() == BENCH:
     sys.path[0] = str(ROOT)     # keep bench/trace.py from shadowing stdlib
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+
+from bench import parts  # noqa: E402
 
 EXIT_NO_DEVICE = 3
 
@@ -109,15 +112,15 @@ class Compiles:
 
 class Tracer:
     """A profiler trace of the window alone (``--trace 1``), with the
-    Python tracer off; the harness's own spans mark the window."""
+    Python tracer off; the harness spans that the cell's path opens
+    (``spans``, the window's first) mark the window."""
 
-    SPANS = ("loader.window", "loader.next", "consumer.step")
-
-    def __init__(self, enabled: bool, directory: Path) -> None:
-        self.enabled, self.dir = enabled, directory
+    def __init__(self, enabled: bool, directory: Path,
+                 spans: tuple[str, ...]) -> None:
+        self.enabled, self.dir, self.spans = enabled, directory, spans
 
     @contextlib.contextmanager
-    def window(self, span: str):
+    def window(self):
         import jax
         from jax.profiler import TraceAnnotation
         if not self.enabled:
@@ -129,7 +132,7 @@ class Tracer:
         opts.host_tracer_level = 2
         jax.profiler.start_trace(str(self.dir), profiler_options=opts)
         try:
-            with TraceAnnotation(span):
+            with TraceAnnotation(self.spans[0]):
                 yield
         finally:
             jax.profiler.stop_trace()
@@ -141,16 +144,13 @@ class Tracer:
         files = glob.glob(str(self.dir / "plugins/profile/*/*.xplane.pb"))
         if not files:
             return None
-        return reduce_file(max(files, key=os.path.getmtime), self.SPANS)
-
-
-def make_store(spec: dict):
-    from repro.storage.kv import MemKV
-    return {"mem": MemKV}[spec["kind"]]()
+        return reduce_file(max(files, key=os.path.getmtime), self.spans)
 
 
 class Cell:
-    """One configuration's history, index and store, for one seed."""
+    """One configuration's history, index and store, for one seed.  A
+    store that keeps files keeps them in ``store_dir``, under
+    ``bench/.run/``, one for each cell and seed; ``close`` removes it."""
 
     def __init__(self, name: str, config: dict, seed: int,
                  seconds: float) -> None:
@@ -158,14 +158,17 @@ class Cell:
         from repro.core import GraphManager
         self.name, self.config, self.seed = name, config, seed
         self.seconds = float(seconds)
+        generate = history.generator(config["history"])
+        self.store_dir = RUN_DIR / "stores" / f"{name}.{seed}"
+        shutil.rmtree(self.store_dir, ignore_errors=True)
+        self.store = parts.store(config["store"], self.store_dir)
         t = time.monotonic()
-        own = history.generate(config["history"], seed)
+        own = generate(seed)
         self.sizes = {"nodes": own.num_nodes, "edges": own.num_edges}
         self.hist = history.with_capacity(own, config["universe"])
         uni, ev = history.to_program(self.hist)
         self.timings = {"generate_s": time.monotonic() - t}
         t = time.monotonic()
-        self.store = make_store(config["store"])
         idx, caches = config["index"], config["caches"]
         self.gm = GraphManager(uni, ev, store=self.store, L=idx["L"],
                                k=idx["k"], diff_fn=idx["diff_fn"], **caches)
@@ -174,12 +177,16 @@ class Cell:
         self.t0 = self.t_end = None     # the window, set by the driver
 
     def counters(self) -> dict:
-        """The program's own counters, read as they stand."""
-        return {"kv_gets": self.gm.store.stats.gets}
+        """The program's own counters, read as they stand: the store's
+        gets, and those that missed a hot tier (0 for a store without
+        one), each a read of the cold store."""
+        stats = self.gm.store.stats
+        return {"kv_gets": stats.gets, "kv_hot_misses": stats.hot_misses}
 
     def close(self) -> None:
         self.gm.close()
         self.store.close()
+        shutil.rmtree(self.store_dir, ignore_errors=True)
 
 
 class Run:
@@ -194,11 +201,7 @@ class Run:
 
 
 def read_metric(name: str, run: Run):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", BENCH / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    return parts.find("metrics", name).read(run)
 
 
 def cell_metrics(bench: dict, workload: str, traced: bool) -> list[dict]:
@@ -218,6 +221,7 @@ def main(argv=None, *, allow_cpu: bool = False) -> int:
                          "measurement")
     args = ap.parse_args(argv)
     bench, wl, config, traffic = load_cell(args.workload, args.rehearse)
+    path = parts.find("paths", traffic["path"])
 
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -238,14 +242,13 @@ def main(argv=None, *, allow_cpu: bool = False) -> int:
         + ("  REHEARSAL: not a measurement" if dev.platform != "tpu" else ""))
     compiles = Compiles()
 
-    from bench import load as loads
     cell = Cell(args.workload, config, args.seed, args.seconds)
-    driver = loads.PATHS[traffic["path"]](cell, traffic)
+    driver = path.Driver(cell, traffic)
     t = time.monotonic()
     driver.build()
     driver.warm()
     cell.timings["build_and_warm_s"] = time.monotonic() - t
-    tracer = Tracer(bool(args.trace), RUN_DIR / "trace")
+    tracer = Tracer(bool(args.trace), RUN_DIR / "trace", path.SPANS)
     before = cell.counters()
     log(f"window: opens after {time.monotonic() - T_START:.3f}s of set-up")
     window = driver.drive(tracer)

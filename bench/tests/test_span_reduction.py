@@ -84,8 +84,9 @@ def test_harness_numbers_unchanged_by_program_spans():
                ("consumer.step", 50 * MS, 50 * MS)]
     program = [("retrieve.plan", 200 * MS, 1 * MS),
                ("host.pack", 60 * MS, 5 * MS)]
-    before = trace.reduce_events(device, harness)
-    after = trace.reduce_events(device, harness + program)
+    before = trace.reduce_events(device, harness, "loader.window")
+    after = trace.reduce_events(device, harness + program,
+                                 "loader.window")
     for k in ("window_s", "busy_s", "kernels"):
         assert after[k] == before[k]
     r = ps.reduce_lines(device, [[(n, s, d, {}) for n, s, d in harness]

@@ -33,7 +33,7 @@ def test_reduction_busy_idle_kernels_and_breakdown():
     host = [("loader.window", 0, 100 * ms),
             ("loader.next", 0, 50 * ms),
             ("consumer.step", 50 * ms, 50 * ms)]
-    r = trace.reduce_events(device, host)
+    r = trace.reduce_events(device, host, "loader.window")
     assert r["window_s"] == pytest.approx(0.1)
     # busy: [10, 15) and [95, 100) ms
     assert r["busy_s"] == pytest.approx(0.010)
@@ -51,7 +51,8 @@ def test_reduction_busy_idle_kernels_and_breakdown():
 
 
 def test_no_window_span_gives_nothing():
-    assert trace.reduce_events({"/device:TPU:0": []}, []) is None
+    assert trace.reduce_events({"/device:TPU:0": []}, [],
+                               "loader.window") is None
 
 
 def test_union_and_gaps():

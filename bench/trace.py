@@ -1,8 +1,9 @@
 """Reduction of a profiler trace (``.xplane.pb``) to the numbers the
 benchmark reports.
 
-* The window is the harness's own window span (``loader.window``) on
-  the host's clock, which the device planes share.
+* The window is the harness's own window span, the first of the spans
+  the cell's path opens (``loader.window`` for the loader), on the
+  host's clock, which the device planes share.
 * Device busy time is the union of the intervals in which an operation
   or a program ran on a device (lines ``XLA Ops`` and ``XLA Modules`` of
   each ``/device:TPU:n`` plane), clipped to the window and averaged over
@@ -26,7 +27,6 @@ import re
 from collections import Counter, defaultdict
 
 DEVICE_LINES = ("XLA Ops", "XLA Modules")
-WINDOW_SPANS = ("loader.window",)
 _OP = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)?\s*=")
 _MODULE = re.compile(r"^(.*?)(?:\(\d+\))?$")
 
@@ -64,14 +64,15 @@ def innermost(spans: list[tuple[float, float, str]], t: float) -> str:
 
 
 def reduce_events(device: dict[str, list[tuple[str, str, float, float]]],
-                  host: list[tuple[str, float, float]], top: int = 10
-                  ) -> dict | None:
+                  host: list[tuple[str, float, float]], window: str,
+                  top: int = 10) -> dict | None:
     """The reduction over plain event lists, times in nanoseconds.
 
     ``device`` maps a device plane to its ``(line, name, start, dur)``
-    events; ``host`` lists ``(name, start, dur)`` harness spans.  Returns
-    ``None`` when no window span is present."""
-    wins = [(s, s + d) for n, s, d in host if n in WINDOW_SPANS]
+    events; ``host`` lists ``(name, start, dur)`` harness spans, of which
+    those named ``window`` mark the window.  Returns ``None`` when no
+    window span is present."""
+    wins = [(s, s + d) for n, s, d in host if n == window]
     if not wins:
         return None
     lo, hi = min(a for a, _ in wins), max(b for _, b in wins)
@@ -130,12 +131,13 @@ def reduce_events(device: dict[str, list[tuple[str, str, float, float]]],
 
 
 def reduce_file(path: str, span_names) -> dict | None:
-    """Reads an ``.xplane.pb`` with ``jax.profiler.ProfileData``."""
+    """Reads an ``.xplane.pb`` with ``jax.profiler.ProfileData``; the
+    first of the harness's ``span_names`` marks the window."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     device: dict[str, list] = {}
     host: list = []
-    names = set(span_names) | set(WINDOW_SPANS)
+    names = set(span_names)
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
             device[plane.name] = [
@@ -146,4 +148,4 @@ def reduce_file(path: str, span_names) -> dict | None:
             host += [(e.name, e.start_ns, e.duration_ns)
                      for line in plane.lines for e in line.events
                      if e.name in names]
-    return reduce_events(device, host)
+    return reduce_events(device, host, span_names[0])
