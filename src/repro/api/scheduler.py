@@ -15,11 +15,13 @@ its own envelopes in its own request order.
 
 SLO machinery, layered in dispatch order:
 
-* **Admission control** (at ``submit``): when queued work — queue depth x
-  estimated plan cost, converted to seconds through an EWMA of the
-  observed cost-units-per-second execution rate — exceeds the configured
-  drain horizon (``admit_horizon_ms``), the request is shed immediately
-  with a typed ``overloaded`` envelope.  Shedding keeps the p99 of
+* **Admission control** (at ``submit``): when every worker is taken and
+  queued work — queue depth x estimated plan cost, converted to seconds
+  through an EWMA of the observed cost-units-per-second execution rate —
+  exceeds the configured drain horizon (``admit_horizon_ms``), the
+  request is shed immediately with a typed ``overloaded`` envelope.  A
+  request that a free worker takes at once waits only for the batching
+  window, whatever the work already running costs.  Shedding keeps the p99 of
   *admitted* requests bounded as offered load passes capacity
   (the shed-vs-meltdown gate in BENCH_server.json).
 
@@ -51,6 +53,7 @@ from concurrent.futures import Future, ThreadPoolExecutor
 from typing import TYPE_CHECKING, Any, Sequence
 
 from ..core.errors import DeadlineError, OverloadedError
+from ..runtime.spans import timed
 from .document import GraphQuery
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -111,11 +114,13 @@ class BatchingScheduler:
         # the window looks like an empty queue and the drain-horizon
         # bound silently stops holding
         self._inflight_cost = 0.0
+        self._inflight_units = 0        # dispatched groups not yet done
+        self.workers = max(1, int(workers))
         self._lock = threading.Lock()
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._pool = ThreadPoolExecutor(
-            max_workers=max(1, int(workers)),
+            max_workers=self.workers,
             thread_name_prefix="query-sched")
         self._dispatcher: threading.Thread | None = None
         # cost-units-per-second execution rate (decode-aware plan-cost
@@ -198,8 +203,11 @@ class BatchingScheduler:
         req = _Request(doc, compiled, arrival)
         req.cost_est = self._estimate_cost(compiled)
         with self._lock:
+            # each queued document may become a group of its own
+            busy = (self._inflight_units + len(self._queue)
+                    >= self.workers)
             over = (len(self._queue) >= self.max_queue
-                    or (self.admit_horizon_ms > 0
+                    or (self.admit_horizon_ms > 0 and busy
                         and self._queued_cost + self._inflight_cost
                         + req.cost_est
                         > self.cost_rate.value
@@ -254,6 +262,7 @@ class BatchingScheduler:
             cost = sum(r.cost_est or 0.0 for r in unit)
             with self._lock:
                 self._inflight_cost += cost
+                self._inflight_units += 1
             self._pool.submit(self._run_unit, unit, cost)
 
     # ------------------------------------------------------------ execution
@@ -300,10 +309,10 @@ class BatchingScheduler:
         try:
             self._run_unit_inner(unit)
         finally:
-            if inflight_cost:
-                with self._lock:
-                    self._inflight_cost = max(
-                        0.0, self._inflight_cost - inflight_cost)
+            with self._lock:
+                self._inflight_units -= 1
+                self._inflight_cost = max(
+                    0.0, self._inflight_cost - inflight_cost)
 
     def _run_unit_inner(self, unit: list[_Request]) -> None:
         try:
@@ -311,10 +320,7 @@ class BatchingScheduler:
             live = [r for r in unit if self._check_deadline(r, now)]
             if not live:
                 return
-            t0 = time.perf_counter()
-            results = self._execute(live)
-            wall = time.perf_counter() - t0
-            self._learn(live, results, wall)
+            results = self._dispatch(live, now)
             for req, res in zip(live, results):
                 if not req.future.done():
                     req.future.set_result(res)
@@ -328,6 +334,20 @@ class BatchingScheduler:
         except Exception as e:  # pragma: no cover - defensive backstop
             for req in unit:
                 self._resolve_error(req, e)
+
+    def _dispatch(self, live: list[_Request],
+                  now: float) -> "list[QueryResult]":
+        """Execute one unit of admitted requests under the
+        ``sched.dispatch`` span and learn from it; each answered
+        request's stats gain ``queue_s``, from its enqueue to ``now``,
+        when its unit was dispatched."""
+        with timed("sched.dispatch", docs=len(live)) as sp:
+            results = self._execute(live)
+            self._learn(live, results, sp.elapsed())
+        for req, res in zip(live, results):
+            if res.ok:
+                res.stats["queue_s"] = now - req.arrival
+        return results
 
     def _execute(self, live: list[_Request]) -> "list[QueryResult]":
         svc = self.service
@@ -399,10 +419,7 @@ class BatchingScheduler:
                     results[i] = r.future.result()
             if not live:
                 continue
-            t0 = time.perf_counter()
-            res = self._execute([r for _, r in live])
-            self._learn([r for _, r in live], res,
-                        time.perf_counter() - t0)
+            res = self._dispatch([r for _, r in live], time.perf_counter())
             for (i, _), rr in zip(live, res):
                 results[i] = rr
         return results

@@ -11,8 +11,14 @@ wire.
 Every execution returns a :class:`QueryResult` carrying the payload plus
 execution stats: KV gets/bytes (store-counter deltas — exact single-
 threaded, best-effort attribution under concurrent serving), planner cost
-(decode-aware ``α·stored + β·logical`` units), snapshot-cache hits, and
-wall time.  ``to_dict()``/``to_json()`` render the JSON wire envelope::
+(decode-aware ``α·stored + β·logical`` units), snapshot-cache hits, wall
+time, and the seconds of the document's timed spans
+(:mod:`repro.runtime.spans`): ``advance_s`` (``evolve.advance``) and
+``solve_s`` (``solve.fixpoint``).  Served, the scheduler adds
+``queue_s`` (enqueue to dispatch) and the session ``reply_s``
+(``reply.render``).  ``to_dict()``/``to_json()`` render the JSON wire
+envelope (:func:`dumps` encodes it; a full reply's float32 vectors are
+written with 9 significant digits, which name each value exactly)::
 
     {"v": 1, "ok": true, "kind": "multipoint",
      "result": {"points": [{"t": 50, "nodes": 132, "edges": 410,
@@ -33,6 +39,8 @@ it, so results stay bit-identical across the old and new surfaces
 from __future__ import annotations
 
 import dataclasses
+import json
+import secrets
 import time
 import zlib
 from typing import TYPE_CHECKING, Any, Sequence
@@ -42,6 +50,7 @@ import numpy as np
 from ..core.errors import ExecutionError, QueryError
 from ..core.materialize import SnapshotCache
 from ..core.query import AttrOptions
+from ..runtime.spans import collect
 from .compiler import CompiledQuery, QueryCompiler
 from .document import GraphQuery
 
@@ -78,11 +87,15 @@ def _state_payload(st: "MaterializedState", full: bool,
     return out
 
 
-def _jsonable(v: Any, full: bool) -> Any:
+def _jsonable(v: Any, full: bool, vectors: bool = False) -> Any:
     """Best-effort JSON projection of an operator value: arrays summarize
-    to size+CRC unless ``full``."""
+    to size+CRC unless ``full``.  With ``vectors``, a full finite 1-D
+    float32 array stays an array, for :func:`dumps` to write."""
     if isinstance(v, np.ndarray):
         if full:
+            if (vectors and v.ndim == 1 and v.dtype == np.float32
+                    and np.isfinite(v).all()):
+                return v
             return v.tolist()
         return {"size": int(v.size), "dtype": str(v.dtype), "crc": _crc(v)}
     if isinstance(v, (np.integer,)):
@@ -90,10 +103,69 @@ def _jsonable(v: Any, full: bool) -> Any:
     if isinstance(v, (np.floating,)):
         return float(v)
     if isinstance(v, dict):
-        return {str(k): _jsonable(x, full) for k, x in v.items()}
+        return {str(k): _jsonable(x, full, vectors) for k, x in v.items()}
     if isinstance(v, (list, tuple)):
-        return [_jsonable(x, full) for x in v]
+        return [_jsonable(x, full, vectors) for x in v]
     return v
+
+
+_DIGITS = 10 ** np.arange(8, -1, -1, dtype=np.int64)
+
+
+def _float32_text(a: np.ndarray) -> str:
+    """A JSON list of a finite 1-D float32 array, each value as
+    ``±d.dddddddde±dd``: 9 significant digits, enough to name every float32
+    exactly.  numpy does the work, so the interpreter lock is held in
+    short pieces (``json`` holds it for the whole list: 0.3 s for the
+    8 × 33,333 ranks of an 8-point PageRank reply)."""
+    x = a.astype(np.float64)
+    mag = np.abs(x)
+    live = mag > 0
+    e = np.floor(np.log10(np.where(live, mag, 1.0))).astype(np.int64)
+    m = np.rint(mag * 10.0 ** (8 - e)).astype(np.int64)
+    low = live & (m < _DIGITS[0])       # log10 rounded up to an integer
+    e -= low
+    m = np.where(low, np.rint(mag * 10.0 ** (8 - e)).astype(np.int64), m)
+    high = m >= 10 * _DIGITS[0]         # rounding carried a digit
+    e += high
+    m = np.where(high, m // 10, m)
+    digits = (m[:, None] // _DIGITS) % 10 + ord("0")
+    out = np.empty((x.size, 16), np.uint8)
+    out[:, 0] = np.where(x < 0, ord("-"), ord(" "))
+    out[:, 1] = digits[:, 0]
+    out[:, 2] = ord(".")
+    out[:, 3:11] = digits[:, 1:]
+    out[:, 11] = ord("e")
+    out[:, 12] = np.where(e < 0, ord("-"), ord("+"))
+    out[:, 13] = np.abs(e) // 10 + ord("0")
+    out[:, 14] = np.abs(e) % 10 + ord("0")
+    out[:, 15] = ord(",")
+    return "[" + out.tobytes()[:-1].decode("ascii") + "]"
+
+
+def dumps(env: dict) -> str:
+    """``json.dumps(env, sort_keys=True)`` of an envelope from
+    ``to_dict(vectors=True)``: each float32 array left in it is written by
+    :func:`_float32_text`, spliced where ``json`` put a one-off marker."""
+    texts: list[str] = []
+    mark = secrets.token_hex(16)
+
+    def park(o):
+        if not isinstance(o, np.ndarray):
+            raise TypeError(f"{type(o).__name__} is not JSON serializable")
+        texts.append(_float32_text(o))
+        return mark
+
+    line = json.dumps(env, sort_keys=True, default=park)
+    if not texts:
+        return line
+    parts = line.split(f'"{mark}"')
+    if len(parts) != len(texts) + 1:    # a string of the envelope held it
+        return json.dumps(env, sort_keys=True, default=np.ndarray.tolist)
+    out = [parts[0]]
+    for text, part in zip(texts, parts[1:]):
+        out += [text, part]
+    return "".join(out)
 
 
 @dataclasses.dataclass
@@ -107,7 +179,7 @@ class QueryResult:
     error: QueryError | None = None
     query: GraphQuery | None = None
 
-    def _payload(self, full: bool) -> Any:
+    def _payload(self, full: bool, vectors: bool) -> Any:
         v = self.value
         q = self.query
         wa = bool(q is not None and (q.attrs.wants_attrs
@@ -127,11 +199,14 @@ class QueryResult:
         if self.kind == "evolve":
             return {"times": [int(t) for t in v.times],
                     "incremental": bool(v.stats.get("incremental", True)),
-                    "values": [_jsonable(x, full) for x in v.values],
+                    "values": [_jsonable(x, full, vectors)
+                               for x in v.values],
                     "engine_stats": _jsonable(v.stats, False)}
-        return _jsonable(v, full)
+        return _jsonable(v, full, vectors)
 
-    def to_dict(self) -> dict:
+    def to_dict(self, vectors: bool = False) -> dict:
+        """The wire envelope as plain JSON values; with ``vectors``, a full
+        reply's float32 arrays stay arrays, for :func:`dumps`."""
         if not self.ok:
             out = {"v": 1, "ok": False, "kind": self.kind,
                    "error": self.error.to_dict()}
@@ -139,7 +214,7 @@ class QueryResult:
             full = bool(self.query is not None
                         and self.query.reply == "full")
             out = {"v": 1, "ok": True, "kind": self.kind,
-                   "result": self._payload(full),
+                   "result": self._payload(full, vectors),
                    "stats": _jsonable(self.stats, False)}
         # correlation id echo (cross-wiring oracle under concurrent
         # serving): every envelope names the request it answers
@@ -148,8 +223,7 @@ class QueryResult:
         return out
 
     def to_json(self) -> str:
-        import json
-        return json.dumps(self.to_dict(), sort_keys=True)
+        return dumps(self.to_dict(vectors=True))
 
 
 # ---------------------------------------------------------------------------
@@ -279,7 +353,7 @@ class QueryService:
         pts = cq.point_times
         # one pin for the whole document: retrieval and finish() (interval /
         # evolve engine calls included) resolve against one index version
-        with self.gm.epochs.acquire() as pin:
+        with self.gm.epochs.acquire() as pin, collect() as spent:
             if pts:
                 states, rstats = self.retrieve_points(
                     pts, cq.options, cq.doc.use_current, cq.doc.no_cache,
@@ -288,7 +362,9 @@ class QueryService:
             else:
                 rstats = {"epoch": pin.id, "epoch_events": pin.data.n_events}
                 value = cq.finish(self, None, dg=pin.data.dg)
-        stats = {**clock.done(), **rstats, "targets": len(pts)}
+        stats = {**clock.done(), **rstats, "targets": len(pts),
+                 "advance_s": spent.get("evolve.advance", 0.0),
+                 "solve_s": spent.get("solve.fixpoint", 0.0)}
         return QueryResult(cq.kind, True, value, stats, query=cq.doc)
 
     def run(self, doc: GraphQuery) -> QueryResult:

@@ -34,7 +34,7 @@ from typing import Any, Callable, Sequence
 
 import numpy as np
 
-from ..runtime.spans import span
+from ..runtime.spans import span, timed
 from ..storage import columnar as col
 from .events import (EV_DEL_EDGE, EV_DEL_NODE, EV_NEW_EDGE, EV_NEW_NODE,
                      MaterializedState, apply_events)
@@ -230,9 +230,11 @@ class EvolveOp:
     up to the operator's stated tolerance (exact for counting operators,
     convergence-tol for fixpoint solvers).  ``iters`` (when set) reports
     the last solve's iteration count, the quantity the warm start
-    shrinks."""
+    shrinks, and ``edges`` the edge rows each of its iterations
+    scattered."""
 
     iters: int | None = None
+    edges: int | None = None
 
     def init(self, ctx: EvolveContext, state: MaterializedState,
              t: int) -> Any:
@@ -305,24 +307,24 @@ class PageRankOp(EvolveOp):
     baseline, so iterations scale with how much the graph moved."""
 
     def __init__(self, damping: float = 0.85, tol: float = 1e-6,
-                 max_iters: int = 200) -> None:
+                 max_iters: int | None = None) -> None:
         # tol below ~1e-7 chases float32 segment-sum noise and saturates
         # max_iters on both the warm and cold paths
+        from ..graph.algorithms import PAGERANK_MAX_ITERS
         self.damping = float(damping)
         self.tol = float(tol)
-        self.max_iters = int(max_iters)
+        self.max_iters = int(PAGERANK_MAX_ITERS if max_iters is None
+                             else max_iters)
         self.pr: np.ndarray | None = None
 
     def _solve(self, ctx, state, pr0) -> np.ndarray:
         from ..graph.algorithms import pagerank_fixpoint
         from . import bitmaps as bm
-        pr, iters = pagerank_fixpoint(
+        self.pr, self.iters, self.edges = pagerank_fixpoint(
             ctx.edge_src, ctx.edge_dst, bm.np_pack(state.edge_mask),
             bm.np_pack(state.node_mask), pr0,
             num_nodes=ctx.universe.num_nodes, max_iters=self.max_iters,
             damping=self.damping, tol=self.tol)
-        self.iters = iters
-        self.pr = pr
         return self.pr.copy()
 
     def init(self, ctx, state, t):
@@ -344,19 +346,19 @@ class ComponentsOp(EvolveOp):
     are reset and re-flooded; components merged by added edges are
     pre-unioned on the host so a merge costs O(1) HashMin sweeps."""
 
-    def __init__(self, max_iters: int = 4096) -> None:
-        self.max_iters = int(max_iters)
+    def __init__(self, max_iters: int | None = None) -> None:
+        from ..graph.algorithms import COMPONENTS_MAX_ITERS
+        self.max_iters = int(COMPONENTS_MAX_ITERS if max_iters is None
+                             else max_iters)
         self.labels: np.ndarray | None = None
 
     def _solve(self, ctx, state, labels0) -> np.ndarray:
         from ..graph.algorithms import connected_components_fixpoint
         from . import bitmaps as bm
-        labels, iters = connected_components_fixpoint(
+        self.labels, self.iters, self.edges = connected_components_fixpoint(
             ctx.edge_src, ctx.edge_dst, bm.np_pack(state.edge_mask),
             bm.np_pack(state.node_mask), labels0,
             num_nodes=ctx.universe.num_nodes, max_iters=self.max_iters)
-        self.iters = iters
-        self.labels = labels
         return self.labels.copy()
 
     def init(self, ctx, state, t):
@@ -520,13 +522,15 @@ class TemporalEngine:
             state = gm.get_snapshot(times[0], opts, use_current=use_current)
         state = state.resized(uni).copy()
         values = [operator.init(ctx, state, times[0])]
-        iters = [operator.iters]
+        iters, edges = [operator.iters], [operator.edges]
         changes = 0
         for lo, hi in zip(times, times[1:]):
-            state, delta = slicer.advance(state, lo, hi)
+            with timed("evolve.advance"):
+                state, delta = slicer.advance(state, lo, hi)
             changes += delta.n_changes
             values.append(operator.step(ctx, state, delta, hi))
             iters.append(operator.iters)
+            edges.append(operator.edges)
         wall = time.perf_counter() - t_start
         gm.workload.record_interval(dg._leaf_for_time(times[0]),
                                     dg._leaf_for_time(times[-1]),
@@ -534,7 +538,8 @@ class TemporalEngine:
         stats = {"points": len(times), "incremental": True,
                  "elists_fetched": len(slicer._comps),
                  "net_changes": changes, "wall_s": wall,
-                 "solver_iters": iters if iters[0] is not None else None}
+                 "solver_iters": iters if iters[0] is not None else None,
+                 "solver_edges": edges if edges[0] is not None else None}
         return EvolveResult(times, values, stats)
 
     def _recompute(self, times, operator, ctx, opts, use_current,

@@ -9,12 +9,14 @@ snapshots at once (multipoint analytics).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core import bitmaps as bm
+from ..runtime.spans import timed
 
 
 def edge_mask_from_plane(plane: jnp.ndarray, num_edges: int) -> jnp.ndarray:
@@ -121,25 +123,63 @@ def multi_snapshot_pagerank(edge_src, edge_dst, edge_planes, node_planes, *,
 # per-snapshot recompute up to the tolerance.
 
 
-def _edge_bucket(n: int) -> int:
-    """Compact live-edge arrays are padded up to a multiple of 512 so the
-    jit'd fixpoint kernels stay hot in the compile cache across
-    timepoints (live counts drift every snapshot).  Scatter cost scales
-    with the padded length, so the granularity trades wasted lanes
-    (≤ 512 elements) against recompiles (one per crossed boundary)."""
-    return max(512, -(-n // 512) * 512)
+# iteration caps of the fixpoint solvers: the defaults of the evolve
+# operators (core/temporal.py PageRankOp, ComponentsOp), which a served
+# document runs, so compile_fixpoints compiles the programs they call
+PAGERANK_MAX_ITERS = 200
+COMPONENTS_MAX_ITERS = 4096
+
+
+def fixpoint_lengths(num_edges: int) -> tuple[int, ...]:
+    """The padded live-edge lengths the fixpoint solvers use on a universe
+    of ``num_edges`` edge slots: ``m · 2^k`` for ``m`` in 4..7 and
+    ``k ≥ 7`` (512, 640, 768, 896, 1024, 1280, ...), four to a doubling,
+    up to the first length that holds every slot.
+
+    A live count is padded to the smallest length that holds it, so a
+    universe has one compiled program per solver and length, about
+    ``4 · log2(num_edges / 512)`` of them, and :func:`compile_fixpoints`
+    can compile them all before serving; padding wastes under a quarter
+    of the scattered elements.  Every length is a multiple of 128, the
+    TPU's lane width."""
+    out: list[int] = []
+    k = 7
+    while not out or out[-1] < num_edges:
+        out += [m << k for m in (4, 5, 6, 7)]
+        k += 1
+    return tuple(n for i, n in enumerate(out)
+                 if i == 0 or out[i - 1] < num_edges)
+
+
+class Solved(NamedTuple):
+    """A fixpoint solve: its ``value``, the iterations it ran, and the
+    edge rows each iteration scattered (the padded live-edge length; the
+    live count on the dense PageRank path)."""
+    value: np.ndarray
+    iters: int
+    edges: int
+
+
+def _edge_length(n: int, num_edges: int) -> int:
+    """The length :func:`fixpoint_lengths` pads ``n`` live edges to."""
+    for length in fixpoint_lengths(num_edges):
+        if length >= n:
+            return length
+    raise ValueError(f"{n} live edges in a universe of {num_edges} slots")
 
 
 def _compact_edges(edge_src: np.ndarray, edge_dst: np.ndarray,
                    edge_mask: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Drop masked-out edge slots before solving: after churn, live edges
-    are a small fraction of the slot universe, and XLA-CPU scatter cost
-    scales with the number of *scattered elements*, masked or not.
-    Padding rows are (0, 0) with live=0 — segment-summed with zero mass,
-    exactly like a masked slot."""
+    are a small fraction of the slot universe, and scatter cost scales
+    with the number of *scattered elements*, masked or not.  The live
+    edges are padded to the :func:`fixpoint_lengths` length of their
+    count.  Padding rows are (0, 0) with live=0 — segment-summed with
+    zero mass, exactly like a masked slot, so the padding leaves every
+    result bit for bit as it is."""
     live = np.nonzero(edge_mask)[0]
-    Ec = _edge_bucket(live.size)
+    Ec = _edge_length(live.size, edge_mask.size)
     es = np.zeros(Ec, np.int32)
     ed = np.zeros(Ec, np.int32)
     lv = np.zeros(Ec, np.float32)
@@ -222,47 +262,62 @@ DENSE_PAGERANK_MAX_NODES = 1024
 
 
 def pagerank_fixpoint(edge_src, edge_dst, edge_plane, node_plane, pr0, *,
-                      num_nodes: int, max_iters: int = 200,
+                      num_nodes: int, max_iters: int = PAGERANK_MAX_ITERS,
                       damping: float = 0.85, tol: float = 1e-6,
-                      force_impl: str | None = None
-                      ) -> tuple[np.ndarray, int]:
+                      force_impl: str | None = None) -> Solved:
     """Masked PageRank iterated until the L1 step change drops under
     ``tol`` (or ``max_iters``).  ``pr0`` is the starting vector — pass the
     previous snapshot's ranks (with the touched frontier reset) for the
     incremental path, or a uniform vector for a cold solve.  Returns
-    ``(pr, iters_used)``; the fixpoint is unique, so the result does not
-    depend on ``pr0`` beyond the tolerance.
+    :class:`Solved` ``(pr, iters, edges)``; the fixpoint is unique, so
+    the result does not depend on ``pr0`` beyond the tolerance.
 
-    Host wrapper: compacts the edge list to the live slots and picks the
-    dense-matvec kernel for small node universes
-    (``DENSE_PAGERANK_MAX_NODES``) or the bucketed segment kernel above
-    it — same semantics as solving over the full masked slot universe,
-    at live-edge cost.  ``force_impl`` ("dense" | "segment") pins the
+    Host wrapper, under one ``solve.fixpoint`` span: compacts the edge
+    list to the live slots and picks the dense-matvec kernel for small
+    node universes (``DENSE_PAGERANK_MAX_NODES``) or the segment kernel
+    above it, on live edges padded to a :func:`fixpoint_lengths` length —
+    same semantics as solving over the full masked slot universe, at
+    live-edge cost.  ``force_impl`` ("dense" | "segment") pins the
     kernel, for the equivalence tests."""
     edge_src = np.asarray(edge_src)
     edge_dst = np.asarray(edge_dst)
     E = edge_src.shape[0]
-    emask = bm.np_unpack(np.asarray(edge_plane), E)
     impl = force_impl or ("dense" if num_nodes <= DENSE_PAGERANK_MAX_NODES
                           else "segment")
-    nmask = bm.np_unpack(np.asarray(node_plane), num_nodes
-                         ).astype(np.float32)
-    if impl == "dense":
-        live = np.nonzero(emask)[0]
-        A = np.zeros((num_nodes, num_nodes), np.float32)
-        np.add.at(A, (edge_src[live], edge_dst[live]), 1.0)
-        np.add.at(A, (edge_dst[live], edge_src[live]), 1.0)
-        pr, iters = _pagerank_fixpoint_dense(
-            jnp.asarray(A), jnp.asarray(nmask),
-            jnp.asarray(pr0, jnp.float32), jnp.float32(damping),
-            jnp.float32(tol), max_iters=max_iters)
-    else:
-        es, ed, lv = _compact_edges(edge_src, edge_dst, emask)
-        pr, iters = _pagerank_fixpoint_kernel(
-            jnp.asarray(es), jnp.asarray(ed), jnp.asarray(lv),
-            jnp.asarray(node_plane), jnp.asarray(pr0, jnp.float32),
-            jnp.float32(damping), jnp.float32(tol),
-            num_nodes=num_nodes, max_iters=max_iters)
+    with timed("solve.fixpoint", op="pagerank") as sp:
+        emask = bm.np_unpack(np.asarray(edge_plane), E)
+        if impl == "dense":
+            nmask = bm.np_unpack(np.asarray(node_plane), num_nodes
+                                 ).astype(np.float32)
+            live = np.nonzero(emask)[0]
+            A = np.zeros((num_nodes, num_nodes), np.float32)
+            np.add.at(A, (edge_src[live], edge_dst[live]), 1.0)
+            np.add.at(A, (edge_dst[live], edge_src[live]), 1.0)
+            out = Solved(*_pagerank_dense(A, nmask, pr0, damping, tol,
+                                          max_iters), live.size)
+        else:
+            es, ed, lv = _compact_edges(edge_src, edge_dst, emask)
+            out = Solved(*_pagerank_segment(es, ed, lv, node_plane, pr0,
+                                            damping, tol, num_nodes,
+                                            max_iters), es.size)
+        sp.set(iters=out.iters, edges=out.edges)
+    return out
+
+
+def _pagerank_dense(A, nmask, pr0, damping, tol, max_iters):
+    pr, iters = _pagerank_fixpoint_dense(
+        jnp.asarray(A), jnp.asarray(nmask), jnp.asarray(pr0, jnp.float32),
+        jnp.float32(damping), jnp.float32(tol), max_iters=max_iters)
+    return np.asarray(pr), int(iters)
+
+
+def _pagerank_segment(es, ed, lv, node_plane, pr0, damping, tol, num_nodes,
+                      max_iters):
+    pr, iters = _pagerank_fixpoint_kernel(
+        jnp.asarray(es), jnp.asarray(ed), jnp.asarray(lv),
+        jnp.asarray(node_plane), jnp.asarray(pr0, jnp.float32),
+        jnp.float32(damping), jnp.float32(tol),
+        num_nodes=num_nodes, max_iters=max_iters)
     return np.asarray(pr), int(iters)
 
 
@@ -315,8 +370,8 @@ def _cc_fixpoint_kernel(edge_src, edge_dst, edge_live, node_plane, labels0,
 
 def connected_components_fixpoint(edge_src, edge_dst, edge_plane, node_plane,
                                   labels0, *, num_nodes: int,
-                                  max_iters: int = 4096
-                                  ) -> tuple[np.ndarray, int]:
+                                  max_iters: int = COMPONENTS_MAX_ITERS
+                                  ) -> Solved:
     """HashMin label flooding run to its fixpoint (no label changes).
 
     Starting labels must satisfy the warm-start contract: within every
@@ -324,19 +379,56 @@ def connected_components_fixpoint(edge_src, edge_dst, edge_plane, node_plane,
     (the min live node id), and no node starts below its component's true
     label.  ``arange`` (cold) and the incremental reset of
     :func:`cc_warm_labels` both satisfy it, and then the fixpoint is
-    exactly the cold answer.  Returns ``(labels, iters_used)``.
+    exactly the cold answer.  Returns :class:`Solved`
+    ``(labels, iters, edges)``.
 
-    Host wrapper compacting to live edges, like
-    :func:`pagerank_fixpoint`."""
+    Host wrapper compacting to live edges padded to a
+    :func:`fixpoint_lengths` length, under one ``solve.fixpoint`` span,
+    like :func:`pagerank_fixpoint`."""
     E = np.asarray(edge_src).shape[0]
-    emask = bm.np_unpack(np.asarray(edge_plane), E)
-    es, ed, lv = _compact_edges(np.asarray(edge_src), np.asarray(edge_dst),
-                                emask)
+    with timed("solve.fixpoint", op="components") as sp:
+        emask = bm.np_unpack(np.asarray(edge_plane), E)
+        es, ed, lv = _compact_edges(np.asarray(edge_src),
+                                    np.asarray(edge_dst), emask)
+        out = Solved(*_components_segment(es, ed, lv, node_plane, labels0,
+                                          num_nodes, max_iters), es.size)
+        sp.set(iters=out.iters, edges=out.edges)
+    return out
+
+
+def _components_segment(es, ed, lv, node_plane, labels0, num_nodes,
+                        max_iters):
     labels, iters = _cc_fixpoint_kernel(
         jnp.asarray(es), jnp.asarray(ed), jnp.asarray(lv),
         jnp.asarray(node_plane), jnp.asarray(labels0),
         num_nodes=num_nodes, max_iters=max_iters)
     return np.asarray(labels), int(iters)
+
+
+def compile_fixpoints(num_nodes: int, num_edges: int) -> int:
+    """Compiles, before serving, every program the two fixpoint solvers
+    run on a universe of ``num_nodes`` node and ``num_edges`` edge slots:
+    one per :func:`fixpoint_lengths` length and solver (the dense
+    PageRank kernel once, where the universe takes it).  Each runs once,
+    on no live edge, through the same host calls a solve makes.  Returns
+    the number of programs."""
+    plane = np.zeros(bm.num_words(num_nodes), np.uint32)
+    pr0 = np.zeros(num_nodes, np.float32)
+    labels0 = np.arange(num_nodes, dtype=np.int32)
+    lengths = fixpoint_lengths(num_edges)
+    for n in lengths:
+        es = np.zeros(n, np.int32)
+        lv = np.zeros(n, np.float32)
+        _components_segment(es, es, lv, plane, labels0, num_nodes,
+                            COMPONENTS_MAX_ITERS)
+        if num_nodes > DENSE_PAGERANK_MAX_NODES:
+            _pagerank_segment(es, es, lv, plane, pr0, 0.85, 1e-6, num_nodes,
+                              PAGERANK_MAX_ITERS)
+    if num_nodes <= DENSE_PAGERANK_MAX_NODES:
+        _pagerank_dense(np.zeros((num_nodes, num_nodes), np.float32),
+                        pr0, pr0, 0.85, 1e-6, PAGERANK_MAX_ITERS)
+        return len(lengths) + 1
+    return 2 * len(lengths)
 
 
 def cc_warm_labels(prev_labels: np.ndarray, node_mask: np.ndarray,

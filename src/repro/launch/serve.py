@@ -289,11 +289,14 @@ def serve_server(n_events: int, port: int, seed: int = 0,
     elapses, then prints ``SERVER_STATS <json>``."""
     import json as _json
 
+    from ..graph.algorithms import compile_fixpoints
     from .server import QueryServer
 
     gm, store, ev = _build_query_gm(n_events, seed, codec, kv, kv_dir,
                                     hot_mb, budget_mb, shards,
                                     shard_procs, replicas)
+    # every fixpoint solver length, before the first document
+    compile_fixpoints(gm.universe.num_nodes, gm.universe.num_edges)
     srv = QueryServer(gm, port=port, window_ms=window_ms, workers=workers,
                       admit_horizon_ms=admit_ms,
                       session_lease_mb=session_mb)

@@ -46,8 +46,10 @@ from typing import TYPE_CHECKING, Any, Iterable, Iterator
 
 from ..api.document import GraphQuery
 from ..api.scheduler import BatchingScheduler
+from ..api.service import dumps
 from ..core.errors import BackpressureError
 from ..core.query import AttrOptions
+from ..runtime.spans import timed
 
 if TYPE_CHECKING:  # pragma: no cover
     from ..api.service import QueryResult
@@ -201,16 +203,33 @@ class SessionCore:
 
     # ---------------------------------------------------------- rendering
     def render(self, res: "QueryResult", raw_id=None) -> dict:
-        """QueryResult → wire dict, with lease post-processing and id
-        echo salvaged from the raw line when the document never parsed."""
+        """QueryResult → wire dict (``to_dict(vectors=True)``, for
+        :func:`~repro.api.service.dumps`), with lease post-processing and
+        id echo salvaged from the raw line when the document never
+        parsed."""
         if (res.ok and res.query is not None
                 and res.query.reply == "lease"):
             env = self.attach_leases(res)
         else:
-            env = res.to_dict()
+            env = res.to_dict(vectors=True)
         if raw_id is not None and "id" not in env:
             env["id"] = raw_id
         return env
+
+    def encode(self, res: "QueryResult", raw_id=None) -> str:
+        """One wire line (no newline) under the ``reply.render`` span: the
+        rendered envelope, encoded.  An answered envelope's ``stats`` come
+        last and carry ``reply_s``, the span's seconds up to their own
+        encoding."""
+        with timed("reply.render") as sp:
+            env = self.render(res, raw_id)
+            stats = env.pop("stats", None)
+            line = dumps(env)
+            if stats is not None:
+                stats["reply_s"] = sp.elapsed()
+                line = (f'{line[:-1]}, "stats": '
+                        f'{json.dumps(stats, sort_keys=True)}}}')
+        return line
 
 
 def run_session_lines(core: SessionCore, lines: Iterable[str],
@@ -236,11 +255,9 @@ def run_session_lines(core: SessionCore, lines: Iterable[str],
                 yield json.dumps(core.handle_control(item[1]),
                                  sort_keys=True)
             elif item[0] == "err":
-                yield json.dumps(core.render(item[1], item[2]),
-                                 sort_keys=True)
+                yield core.encode(item[1], item[2])
             else:
-                yield json.dumps(core.render(next(results), item[2]),
-                                 sort_keys=True)
+                yield core.encode(next(results), item[2])
 
     chunk: list = []
     for line in lines:
@@ -301,13 +318,13 @@ class _Session(threading.Thread):
                     # that lease attached (strict per-session ordering)
                     self._out.put(("control", item[1]))
                 elif item[0] == "err":
-                    self._out.put(("ready",
-                                   self.core.render(item[1], item[2])))
+                    self._out.put(("ready", item[1], item[2]))
                 else:
                     _, doc, raw_id = item
                     if self.core.over_budget():
-                        self._out.put(("ready", self.core.render(
-                            self.core.shed_backpressure(doc), raw_id)))
+                        self._out.put(("ready",
+                                       self.core.shed_backpressure(doc),
+                                       raw_id))
                         continue
                     fut = self.server.scheduler.submit(doc)
                     self._out.put(("future", fut, raw_id))
@@ -335,14 +352,14 @@ class _Session(threading.Thread):
                 break
             try:
                 if entry[0] == "ready":
-                    env = entry[1]
+                    line = self.core.encode(*entry[1:])
                 elif entry[0] == "control":
-                    env = self.core.handle_control(entry[1])
+                    line = json.dumps(self.core.handle_control(entry[1]),
+                                      sort_keys=True)
                 else:
                     _, fut, raw_id = entry
-                    env = self.core.render(fut.result(timeout=120),
-                                           raw_id)
-                data = (json.dumps(env, sort_keys=True) + "\n").encode()
+                    line = self.core.encode(fut.result(timeout=120), raw_id)
+                data = (line + "\n").encode()
                 self.conn.sendall(data)
             except (OSError, ValueError):
                 break     # client went away mid-write

@@ -4,10 +4,12 @@ that carry their bytes (`runtime/staging.py`).
 One `SnapshotBatchLoader` batch runs under a profiler trace on the CPU;
 `loader.batch` must close before the batch reaches its consumer, and the
 copy spans must carry exactly the bytes the batch's copies move.  Two
-Pallas batches on a fresh universe run under another trace, which must
-hold every program span, `kernel.bucket` among them.  A source scan pins
-`NAMES` to the span literals in `src/`, so a renamed span cannot drop out
-of a trace reader that selects by `NAMES`.
+Pallas batches on a fresh universe run under another trace, and served
+evolve documents under a third; together they must hold every program
+span, `kernel.bucket` and the served path's among them, and a served
+envelope's times must be its spans' times.  A source scan pins `NAMES` to
+the span literals in `src/`, so a renamed span cannot drop out of a trace
+reader that selects by `NAMES`.
 """
 import glob
 import re
@@ -95,10 +97,70 @@ def traced_pallas_batches(tmp_path_factory):
     return {"events": _host_events(trace_dir), "universe": gm.universe}
 
 
-def test_every_span_is_in_the_trace(traced_pallas_batches):
-    ev = traced_pallas_batches["events"]
+@pytest.fixture(scope="module")
+def traced_session(tmp_path_factory):
+    """Two evolve documents and a multipoint one through a socket
+    session under a trace, each compiled outside it first."""
+    import json
+    import socket
+
+    from repro.launch.server import QueryServer
+    gm, loader = _loader()
+    t = loader.times
+    docs = [{"kind": "evolve", "op": op, "times": t, "id": op}
+            for op in ("pagerank", "components")]
+    docs.append({"kind": "multipoint", "times": t[:2], "id": "mp"})
+    trace_dir = tmp_path_factory.mktemp("trace_served")
+    with QueryServer(gm, workers=1) as srv:
+        with socket.create_connection((srv.host, srv.port), timeout=60) as c:
+            f = c.makefile("rw", encoding="utf-8", newline="\n")
+
+            def rpc(doc):
+                f.write(json.dumps(doc) + "\n")
+                f.flush()
+                return json.loads(f.readline())
+
+            for doc in docs:
+                rpc(dict(doc, id="warm"))
+            with jax.profiler.trace(str(trace_dir)):
+                envs = [rpc(doc) for doc in docs]
+            f.close()
+    gm.close()
+    return {"events": _host_events(trace_dir), "envelopes": envs}
+
+
+def test_every_span_is_in_the_trace(traced_pallas_batches, traced_session):
+    ev = dict(traced_pallas_batches["events"])
+    ev.update((k, v) for k, v in traced_session["events"].items() if v)
     missing = [n for n in spans.NAMES if not ev.get(n)]
     assert not missing
+
+
+def test_envelope_times_are_their_spans_times(traced_session):
+    """Each envelope's solve, advance and reply seconds are what its
+    spans cover in the trace (one document at a time, one worker), and
+    each solve span carries its operator, iterations and edge rows."""
+    ev, envs = traced_session["events"], traced_session["envelopes"]
+    dispatch = sorted(ev["sched.dispatch"], key=lambda e: e[1])
+    replies = sorted(ev["reply.render"], key=lambda e: e[1])
+    assert len(dispatch) == len(replies) == len(envs)
+    for env, (line, a, b, st), reply in zip(envs, dispatch, replies):
+        assert st["docs"] == 1
+        for name, key in (("solve.fixpoint", "solve_s"),
+                          ("evolve.advance", "advance_s")):
+            inside = [e for e in ev[name] if e[0] == line and a <= e[1]
+                      and e[2] <= b]
+            secs = sum(e[2] - e[1] for e in inside) / 1e9
+            assert env["stats"][key] == pytest.approx(secs, abs=2e-4)
+        assert env["stats"]["reply_s"] <= (reply[2] - reply[1]) / 1e9
+        if env["kind"] == "evolve":
+            engine = env["result"]["engine_stats"]
+            solves = sorted((e for e in ev["solve.fixpoint"]
+                             if e[0] == line and a <= e[1] and e[2] <= b),
+                            key=lambda e: e[1])
+            assert [e[3]["op"] for e in solves] == [env["id"]] * len(solves)
+            assert [e[3]["iters"] for e in solves] == engine["solver_iters"]
+            assert [e[3]["edges"] for e in solves] == engine["solver_edges"]
 
 
 def test_bucket_tables_build_once_outside_dispatch(traced_pallas_batches):
@@ -166,7 +228,8 @@ def test_to_device_and_to_host_count_exact_bytes(tmp_path):
 def test_span_literals_match_names():
     used = set()
     for path in SRC.rglob("*.py"):
-        used |= set(re.findall(r'\bspan\(\s*"([^"]+)"', path.read_text()))
+        used |= set(re.findall(r'\b(?:span|timed)\(\s*"([^"]+)"',
+                               path.read_text()))
     assert used <= set(spans.NAMES), used - set(spans.NAMES)
     assert set(spans.NAMES) <= used, set(spans.NAMES) - used
     assert len(set(spans.NAMES)) == len(spans.NAMES)
