@@ -318,13 +318,22 @@ class PageRankOp(EvolveOp):
         self.pr: np.ndarray | None = None
 
     def _solve(self, ctx, state, pr0) -> np.ndarray:
-        from ..graph.algorithms import pagerank_fixpoint
+        from ..graph.algorithms import (DENSE_PAGERANK_MAX_NODES,
+                                        pagerank_fixpoint, target_table)
         from . import bitmaps as bm
+        N, E = ctx.universe.num_nodes, len(ctx.edge_src)
+        table = None
+        if N > DENSE_PAGERANK_MAX_NODES:
+            # the bucketed kernel's table is a pure function of the edge
+            # endpoints: kept in the universe's memo, which an append clears
+            table = ctx.universe.memo(
+                ("pagerank.targets", N, E),
+                lambda: target_table(ctx.edge_src, ctx.edge_dst, N))
         self.pr, self.iters, self.edges = pagerank_fixpoint(
             ctx.edge_src, ctx.edge_dst, bm.np_pack(state.edge_mask),
-            bm.np_pack(state.node_mask), pr0,
-            num_nodes=ctx.universe.num_nodes, max_iters=self.max_iters,
-            damping=self.damping, tol=self.tol)
+            bm.np_pack(state.node_mask), pr0, num_nodes=N,
+            max_iters=self.max_iters, damping=self.damping, tol=self.tol,
+            table=table)
         return self.pr.copy()
 
     def init(self, ctx, state, t):

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import bitmaps as bm
+from ..kernels import policy
+from ..kernels.segment_sum.segment_sum import segment_sum_bucketed
 from ..runtime.spans import timed
 
 
@@ -140,8 +142,8 @@ def fixpoint_lengths(num_edges: int) -> tuple[int, ...]:
     universe has one compiled program per solver and length, about
     ``4 · log2(num_edges / 512)`` of them, and :func:`compile_fixpoints`
     can compile them all before serving; padding wastes under a quarter
-    of the scattered elements.  Every length is a multiple of 128, the
-    TPU's lane width."""
+    of the elements an iteration touches.  Every length is a multiple of
+    128, the TPU's lane width."""
     out: list[int] = []
     k = 7
     while not out or out[-1] < num_edges:
@@ -153,8 +155,8 @@ def fixpoint_lengths(num_edges: int) -> tuple[int, ...]:
 
 class Solved(NamedTuple):
     """A fixpoint solve: its ``value``, the iterations it ran, and the
-    edge rows each iteration scattered (the padded live-edge length; the
-    live count on the dense PageRank path)."""
+    padded live-edge length its iterations ran over (the live count on
+    the dense PageRank path)."""
     value: np.ndarray
     iters: int
     edges: int
@@ -189,26 +191,146 @@ def _compact_edges(edge_src: np.ndarray, edge_dst: np.ndarray,
     return es, ed, lv
 
 
-@functools.partial(jax.jit, static_argnames=("num_nodes", "max_iters"))
-def _pagerank_fixpoint_kernel(edge_src, edge_dst, edge_live, node_plane,
+# The segment PageRank path gathers each live directed entry's source
+# contribution and reduces it into its target without a scatter: a
+# universe's 2E directed entries are sorted by target once
+# (:func:`target_table`); a solve compacts the live ones in that order
+# and lays them into rows of ROW_LANES entries, each row inside one
+# TARGET_BLOCK of consecutive targets (:func:`row_layout`), so that an
+# iteration is one gather, one one-hot matmul per row (the bucketed
+# ``segment_sum`` kernel, ROW_GROUP rows to a grid step) and a fold of
+# rows into their blocks.  A grid step's one-hot is block-diagonal, G²
+# rows' worth of work for G rows, against a fixed cost per step: few
+# rows to a step.
+TARGET_BLOCK = 128
+ROW_LANES = 128
+ROW_GROUP = 2
+
+
+class TargetTable(NamedTuple):
+    """A universe's directed edge entries, stably sorted by ``target``:
+    entry ``(target, other, slot)`` sends ``other``'s contribution to
+    ``target`` while edge ``slot`` is live, two entries an edge slot.
+    ``block_start[b]`` is the first entry whose target lies in the
+    ``b``-th :data:`TARGET_BLOCK` of nodes (``NB + 1`` of them)."""
+    target: np.ndarray
+    other: np.ndarray
+    slot: np.ndarray
+    block_start: np.ndarray
+
+
+def target_table(edge_src: np.ndarray, edge_dst: np.ndarray,
+                 num_nodes: int) -> TargetTable:
+    """The :class:`TargetTable` of a universe's edge endpoints: a pure
+    function of them and ``num_nodes``, so a caller builds it once per
+    universe shape and passes it to :func:`pagerank_fixpoint`."""
+    E = len(edge_src)
+    target = np.concatenate([edge_dst, edge_src]).astype(np.int32)
+    order = np.argsort(target, kind="stable")
+    other = np.concatenate([edge_src, edge_dst]).astype(np.int32)[order]
+    slot = np.concatenate([np.arange(E, dtype=np.int32)] * 2)[order]
+    target = target[order]
+    NB = -(-num_nodes // TARGET_BLOCK)
+    block_start = np.searchsorted(
+        target, np.arange(NB + 1, dtype=np.int64) * TARGET_BLOCK)
+    return TargetTable(target, other, slot, block_start)
+
+
+def _row_count(edges: int, num_nodes: int) -> int:
+    """Rows of :func:`row_layout` for ``edges`` padded live edges: their
+    ``2·edges`` directed entries fill ``⌈2·edges / ROW_LANES⌉`` rows, and
+    each target block leaves at most one row part full, so ``+ NB``;
+    rounded up to whole grid steps of ROW_GROUP rows.  One count per
+    :func:`fixpoint_lengths` length, whatever the skew between blocks."""
+    NB = -(-num_nodes // TARGET_BLOCK)
+    rows = -(-2 * edges // ROW_LANES) + NB
+    return -(-rows // ROW_GROUP) * ROW_GROUP
+
+
+class RowLayout(NamedTuple):
+    """A solve's live directed entries laid into ``R`` rows of
+    :data:`ROW_LANES`: ``other`` [R·C] the entry's source (``num_nodes``,
+    a slot of zero contribution, on padding lanes), ``local`` [R·C] its
+    target's offset in the row's block (−1 on padding lanes),
+    ``row_block`` [R] the row's target block (``NB``, no block, on unused
+    rows), and ``deg`` [N] the live degree of every node."""
+    other: np.ndarray
+    local: np.ndarray
+    row_block: np.ndarray
+    deg: np.ndarray
+
+
+def row_layout(table: TargetTable, edge_mask: np.ndarray, num_nodes: int,
+               rows: int) -> RowLayout:
+    """Compacts ``table``'s live entries (those whose edge slot is set in
+    ``edge_mask``) in table order, so they stay sorted by target, and
+    lays each target block's into ``⌈c / ROW_LANES⌉`` rows of its own,
+    in ``rows`` rows (at least :func:`_row_count` of the live count)."""
+    C = ROW_LANES
+    live = np.flatnonzero(np.take(edge_mask, table.slot))
+    target = table.target[live]
+    cstart = np.searchsorted(live, table.block_start)
+    counts = np.diff(cstart)
+    rstart = np.zeros(counts.size + 1, np.int64)
+    np.cumsum(-(-counts // C), out=rstart[1:])
+    if rstart[-1] > rows:
+        raise ValueError(f"{live.size} live entries need {rstart[-1]} "
+                         f"rows, more than {rows}")
+    pos = np.arange(live.size) + np.repeat(rstart[:-1] * C - cstart[:-1],
+                                           counts)
+    other = np.full(rows * C, num_nodes, np.int32)
+    other[pos] = table.other[live]
+    local = np.full(rows * C, -1, np.int32)
+    local[pos] = target & (TARGET_BLOCK - 1)      # a power of two
+    row_block = np.full(rows, counts.size, np.int32)
+    row_block[: rstart[-1]] = np.repeat(
+        np.arange(counts.size, dtype=np.int32), np.diff(rstart))
+    deg = np.bincount(target, minlength=num_nodes).astype(np.float32)
+    return RowLayout(other, local, row_block, deg)
+
+
+@functools.partial(jax.jit, static_argnames=("num_nodes", "max_iters",
+                                             "impl", "interpret"))
+def _pagerank_fixpoint_kernel(other, local, row_block, deg, node_plane,
                               pr0, damping, tol, *, num_nodes: int,
-                              max_iters: int):
-    nmask = bm.unpack(node_plane, num_nodes).astype(jnp.float32)
-    deg = (jax.ops.segment_sum(edge_live, edge_src, num_segments=num_nodes)
-           + jax.ops.segment_sum(edge_live, edge_dst, num_segments=num_nodes))
+                              max_iters: int, impl: str, interpret: bool):
+    N, R = num_nodes, row_block.shape[0]
+    C, B, G = ROW_LANES, TARGET_BLOCK, ROW_GROUP
+    NB = -(-N // B)
+    nmask = bm.unpack(node_plane, N).astype(jnp.float32)
     inv_deg = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1), 0.0)
     n_live = jnp.maximum(nmask.sum(), 1.0)
     # project the start onto the live-node simplex (masks may have changed)
     pr0 = jnp.maximum(pr0, 0.0) * nmask
     s0 = pr0.sum()
     pr0 = jnp.where(s0 > 0, pr0 / jnp.maximum(s0, 1e-30), nmask / n_live)
+    local = local.reshape(R, C)
+
+    if impl == "pallas":
+        # G rows to a grid step: row i of a step owns outputs i·B..i·B+B-1
+        lid = jnp.where(local >= 0, local + (jnp.arange(R) % G)[:, None] * B,
+                        -1).reshape(R // G, G * C)
+        fold = (row_block[None, :] == jnp.arange(NB)[:, None]
+                ).astype(jnp.float32)                       # [NB, R]
+
+        def reduce(g):
+            part = segment_sum_bucketed(g.reshape(R // G, 1, G * C), lid,
+                                        block_n=G * B, interpret=interpret)
+            return jnp.dot(fold, part.reshape(R, B),
+                           precision=jax.lax.Precision.HIGHEST)
+    else:
+        # the same rows as sorted segment ids; padding lanes add their
+        # zero to their block's last id, unused rows fall past the end
+        ids = (row_block[:, None] * B
+               + jnp.where(local >= 0, local, B - 1)).reshape(-1)
+
+        def reduce(g):
+            return jax.ops.segment_sum(g, ids, num_segments=NB * B,
+                                       indices_are_sorted=True)
 
     def step(pr):
-        contrib = pr * inv_deg
-        agg = (jax.ops.segment_sum(contrib[edge_src] * edge_live, edge_dst,
-                                   num_segments=num_nodes)
-               + jax.ops.segment_sum(contrib[edge_dst] * edge_live, edge_src,
-                                     num_segments=num_nodes))
+        contrib = jnp.append(pr * inv_deg, 0.0)     # [N + 1]: padding's 0
+        agg = reduce(contrib[other]).reshape(-1)[:N]
         dangling = (pr * (deg == 0)).sum()
         return nmask * ((1 - damping) / n_live
                         + damping * (agg + dangling / n_live))
@@ -264,6 +386,7 @@ DENSE_PAGERANK_MAX_NODES = 1024
 def pagerank_fixpoint(edge_src, edge_dst, edge_plane, node_plane, pr0, *,
                       num_nodes: int, max_iters: int = PAGERANK_MAX_ITERS,
                       damping: float = 0.85, tol: float = 1e-6,
+                      table: TargetTable | None = None,
                       force_impl: str | None = None) -> Solved:
     """Masked PageRank iterated until the L1 step change drops under
     ``tol`` (or ``max_iters``).  ``pr0`` is the starting vector — pass the
@@ -272,13 +395,18 @@ def pagerank_fixpoint(edge_src, edge_dst, edge_plane, node_plane, pr0, *,
     :class:`Solved` ``(pr, iters, edges)``; the fixpoint is unique, so
     the result does not depend on ``pr0`` beyond the tolerance.
 
-    Host wrapper, under one ``solve.fixpoint`` span: compacts the edge
-    list to the live slots and picks the dense-matvec kernel for small
-    node universes (``DENSE_PAGERANK_MAX_NODES``) or the segment kernel
-    above it, on live edges padded to a :func:`fixpoint_lengths` length —
-    same semantics as solving over the full masked slot universe, at
-    live-edge cost.  ``force_impl`` ("dense" | "segment") pins the
-    kernel, for the equivalence tests."""
+    Host wrapper, under one ``solve.fixpoint`` span: picks the
+    dense-matvec kernel for small node universes
+    (``DENSE_PAGERANK_MAX_NODES``) or the bucketed segment kernel above
+    it, over the live entries of ``table`` (the universe's
+    :func:`target_table`, built here when not given) laid into the
+    :func:`_row_count` rows of their padded :func:`fixpoint_lengths`
+    length — same semantics as solving over the full masked slot
+    universe, at live-edge cost.  The span carries ``impl``
+    (``bucketed`` | ``dense``) and ``rows``, the entries an iteration
+    reads (gathered, padding included; the matrix's on the dense path).
+    ``force_impl`` ("dense" | "segment") pins the kernel, for the
+    equivalence tests."""
     edge_src = np.asarray(edge_src)
     edge_dst = np.asarray(edge_dst)
     E = edge_src.shape[0]
@@ -295,11 +423,19 @@ def pagerank_fixpoint(edge_src, edge_dst, edge_plane, node_plane, pr0, *,
             np.add.at(A, (edge_dst[live], edge_src[live]), 1.0)
             out = Solved(*_pagerank_dense(A, nmask, pr0, damping, tol,
                                           max_iters), live.size)
+            sp.set(impl="dense", rows=A.size)
         else:
-            es, ed, lv = _compact_edges(edge_src, edge_dst, emask)
-            out = Solved(*_pagerank_segment(es, ed, lv, node_plane, pr0,
-                                            damping, tol, num_nodes,
-                                            max_iters), es.size)
+            if table is None:
+                table = target_table(edge_src, edge_dst, num_nodes)
+            if table.slot.size != 2 * E:
+                raise ValueError(f"a target table of {table.slot.size} "
+                                 f"entries for {E} edge slots")
+            Ec = _edge_length(int(np.count_nonzero(emask)), E)
+            lay = row_layout(table, emask, num_nodes,
+                             _row_count(Ec, num_nodes))
+            out = Solved(*_pagerank_segment(lay, node_plane, pr0, damping,
+                                            tol, num_nodes, max_iters), Ec)
+            sp.set(impl="bucketed", rows=lay.other.size)
         sp.set(iters=out.iters, edges=out.edges)
     return out
 
@@ -311,13 +447,17 @@ def _pagerank_dense(A, nmask, pr0, damping, tol, max_iters):
     return np.asarray(pr), int(iters)
 
 
-def _pagerank_segment(es, ed, lv, node_plane, pr0, damping, tol, num_nodes,
-                      max_iters):
+def _pagerank_segment(lay: RowLayout, node_plane, pr0, damping, tol,
+                      num_nodes, max_iters, impl=None, interpret=None):
+    """The bucketed kernel on ``lay``; the reducer (the Pallas kernel, or
+    XLA's sorted segment sum) as :mod:`repro.kernels.policy` resolves
+    ``impl`` and ``interpret``."""
+    impl, interpret = policy.resolve(impl, interpret)
     pr, iters = _pagerank_fixpoint_kernel(
-        jnp.asarray(es), jnp.asarray(ed), jnp.asarray(lv),
-        jnp.asarray(node_plane), jnp.asarray(pr0, jnp.float32),
-        jnp.float32(damping), jnp.float32(tol),
-        num_nodes=num_nodes, max_iters=max_iters)
+        *map(jnp.asarray, lay), jnp.asarray(node_plane),
+        jnp.asarray(pr0, jnp.float32), jnp.float32(damping),
+        jnp.float32(tol), num_nodes=num_nodes, max_iters=max_iters,
+        impl=impl, interpret=interpret and impl == "pallas")
     return np.asarray(pr), int(iters)
 
 
@@ -415,6 +555,8 @@ def compile_fixpoints(num_nodes: int, num_edges: int) -> int:
     plane = np.zeros(bm.num_words(num_nodes), np.uint32)
     pr0 = np.zeros(num_nodes, np.float32)
     labels0 = np.arange(num_nodes, dtype=np.int32)
+    none = np.zeros(0, np.int32)
+    table = target_table(none, none, num_nodes)
     lengths = fixpoint_lengths(num_edges)
     for n in lengths:
         es = np.zeros(n, np.int32)
@@ -422,7 +564,9 @@ def compile_fixpoints(num_nodes: int, num_edges: int) -> int:
         _components_segment(es, es, lv, plane, labels0, num_nodes,
                             COMPONENTS_MAX_ITERS)
         if num_nodes > DENSE_PAGERANK_MAX_NODES:
-            _pagerank_segment(es, es, lv, plane, pr0, 0.85, 1e-6, num_nodes,
+            lay = row_layout(table, none.astype(bool), num_nodes,
+                             _row_count(n, num_nodes))
+            _pagerank_segment(lay, plane, pr0, 0.85, 1e-6, num_nodes,
                               PAGERANK_MAX_ITERS)
     if num_nodes <= DENSE_PAGERANK_MAX_NODES:
         _pagerank_dense(np.zeros((num_nodes, num_nodes), np.float32),

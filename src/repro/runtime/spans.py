@@ -39,8 +39,12 @@ envelope reports the same times the trace shows:
 * ``evolve.advance``: ``IntervalSlicer.advance`` from one evolve point
   to the next, on the host;
 * ``solve.fixpoint``: one fixpoint solve (``op``: ``pagerank`` or
-  ``components``; ``iters`` and ``edges``, the padded live-edge length,
-  set when it ends): compaction, upload, the device program, read-back;
+  ``components``; ``iters`` and ``edges``, the padded live-edge length
+  Ec, set when it ends; a PageRank solve adds ``impl``, ``bucketed`` or
+  ``dense``, and ``rows``, the entries an iteration reads: on the
+  bucketed path the R·C lanes it gathers, padding included, on the
+  dense one the N² matrix): compaction, upload, the device program,
+  read-back;
 * ``reply.render``: rendering and encoding a result envelope.
 """
 from __future__ import annotations

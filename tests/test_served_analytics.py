@@ -14,6 +14,7 @@ back as wire lines:
 """
 from __future__ import annotations
 
+import itertools
 import json
 from pathlib import Path
 
@@ -137,12 +138,20 @@ def test_padded_and_unpadded_solves_agree_bit_for_bit(snapshot, op):
     es, ed, lv = alg._compact_edges(uni.edge_src, uni.edge_dst, emask)
     assert es.size in alg.fixpoint_lengths(uni.num_edges) and es.size > live
     pr0 = nm.astype(np.float32) / nm.sum()
+    table = alg.target_table(uni.edge_src, uni.edge_dst, N)
+    # PageRank: the rows the live entries fill, against the padded
+    # length's rows, unused rows routed past every block
+    padded = alg._row_count(es.size, N)
+    NB = -(-N // alg.TARGET_BLOCK)
+    used = int((alg.row_layout(table, emask, N, padded).row_block
+                < NB).sum())
+    assert used < padded
     out = []
-    for n in (live, es.size):
+    for n, rows in ((live, used), (es.size, padded)):
         if op == "pagerank":
             out.append(alg._pagerank_segment(
-                es[:n], ed[:n], lv[:n], nplane, pr0, 0.85, 1e-6, N,
-                alg.PAGERANK_MAX_ITERS))
+                alg.row_layout(table, emask, N, rows), nplane, pr0, 0.85,
+                1e-6, N, alg.PAGERANK_MAX_ITERS))
         else:
             out.append(alg._components_segment(
                 es[:n], ed[:n], lv[:n], nplane, np.arange(N, dtype=np.int32),
@@ -152,19 +161,24 @@ def test_padded_and_unpadded_solves_agree_bit_for_bit(snapshot, op):
 
 
 def test_compile_fixpoints_leaves_no_length_to_compile(snapshot):
-    """After start-up's compile, solves at any live count hit the cache."""
+    """After start-up's compile, solves at any live count hit the cache,
+    also where one target block holds most of the live edge entries."""
     uni, nplane, _, nm = snapshot
     N, E = 1100, uni.num_edges        # above the dense PageRank threshold
     src, dst = uni.edge_src % N, uni.edge_dst % N
+    hot = np.arange(E) % 8 != 0       # 7 edges in 8 inside nodes 384..511
+    skewed = (np.where(hot, 384 + src % 128, src),
+              np.where(hot, 384 + dst % 128, dst))
     nplane = bm.np_pack(np.ones(N, bool))
     assert alg.compile_fixpoints(N, E) == 2 * len(alg.fixpoint_lengths(E))
     kernels = (alg._pagerank_fixpoint_kernel, alg._cc_fixpoint_kernel)
     before = [k._cache_size() for k in kernels]
-    for live in (0, 700, E // 2, E):
+    for (s, d), live in itertools.product(((src, dst), skewed),
+                                          (0, 700, E // 2, E)):
         eplane = bm.np_pack(np.arange(E) < live)
-        alg.pagerank_fixpoint(src, dst, eplane, nplane,
+        alg.pagerank_fixpoint(s, d, eplane, nplane,
                               np.full(N, 1 / N, np.float32), num_nodes=N)
-        alg.connected_components_fixpoint(src, dst, eplane, nplane,
+        alg.connected_components_fixpoint(s, d, eplane, nplane,
                                           np.arange(N, dtype=np.int32),
                                           num_nodes=N)
     assert [k._cache_size() for k in kernels] == before

@@ -161,6 +161,36 @@ def test_envelope_times_are_their_spans_times(traced_session):
             assert [e[3]["op"] for e in solves] == [env["id"]] * len(solves)
             assert [e[3]["iters"] for e in solves] == engine["solver_iters"]
             assert [e[3]["edges"] for e in solves] == engine["solver_edges"]
+            # this universe takes the dense PageRank kernel
+            impls = {e[3].get("impl") for e in solves}
+            assert impls == ({"dense"} if env["id"] == "pagerank" else {None})
+
+
+def test_pagerank_solve_span_carries_impl_and_rows(tmp_path):
+    """A PageRank solve's span says which kernel ran and how many entries
+    an iteration read; ``edges`` stays the padded live-edge length Ec."""
+    from repro.core import bitmaps as bm
+    from repro.graph import algorithms as alg
+    N, E = 1100, 3000                     # above the dense threshold
+    rng = np.random.default_rng(0)
+    src = rng.integers(0, N, E).astype(np.int32)
+    dst = rng.integers(0, N, E).astype(np.int32)
+    emask = rng.random(E) < 0.4
+    args = (src, dst, bm.np_pack(emask), bm.np_pack(np.ones(N, bool)),
+            np.full(N, 1 / N, np.float32))
+    for impl in ("segment", "dense"):         # compiled outside the trace
+        alg.pagerank_fixpoint(*args, num_nodes=N, force_impl=impl)
+    with jax.profiler.trace(str(tmp_path)):
+        solved = [alg.pagerank_fixpoint(*args, num_nodes=N, force_impl=impl)
+                  for impl in ("segment", "dense")]
+    ev = sorted(_host_events(tmp_path)["solve.fixpoint"], key=lambda e: e[1])
+    Ec = alg._edge_length(int(emask.sum()), E)
+    rows = alg._row_count(Ec, N) * alg.ROW_LANES
+    assert [(e[3]["op"], e[3]["impl"], e[3]["rows"], e[3]["edges"],
+             e[3]["iters"]) for e in ev] == [
+        ("pagerank", "bucketed", rows, Ec, solved[0].iters),
+        ("pagerank", "dense", N * N, int(emask.sum()), solved[1].iters)]
+    assert rows >= 2 * Ec
 
 
 def test_bucket_tables_build_once_outside_dispatch(traced_pallas_batches):

@@ -115,3 +115,21 @@ def test_sharded_retrieval_compiles_collective_free(topo):
         spec((4, Wp), P("data", None)), spec((K, 4, Wp), P(None, "data", None)),
         spec((K, 4, Wp), P(None, "data", None))).compile().as_text()
     assert not [c for c in COLLECTIVES if c in hlo]
+
+
+def test_bucketed_pagerank_compiles_at_served_shape(one_chip):
+    """The PageRank fixpoint program with the Pallas reducer, at the
+    served churn history's shapes: 33,333 node slots, live edges padded
+    to 196,608."""
+    from repro.graph import algorithms as alg
+    N, Ec = 33_333, 196_608
+    R = alg._row_count(Ec, N)
+    lanes = R * alg.ROW_LANES
+    hlo = alg._pagerank_fixpoint_kernel.lower(
+        one_chip((lanes,), jnp.int32), one_chip((lanes,), jnp.int32),
+        one_chip((R,), jnp.int32), one_chip((N,), jnp.float32),
+        one_chip((num_words(N),), jnp.uint32), one_chip((N,), jnp.float32),
+        one_chip((), jnp.float32), one_chip((), jnp.float32), num_nodes=N,
+        max_iters=alg.PAGERANK_MAX_ITERS, impl="pallas",
+        interpret=False).compile().as_text()
+    assert "tpu_custom_call" in hlo
