@@ -1,5 +1,5 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived``
-# CSV (the stub contract), plus the dry-run roofline report when available.
+# CSV (the stub contract).
 from __future__ import annotations
 
 import argparse
@@ -14,12 +14,11 @@ def main() -> None:
                     help="small datasets (CI-sized)")
     ap.add_argument("--only", default=None,
                     help="substring filter on benchmark names")
-    ap.add_argument("--dryrun-json", default="dryrun_results.json")
     args = ap.parse_args()
 
     from . import (ingest_bench, materialize_bench, paper_figs, query_bench,
-                   replica_bench, retrieval_bench, roofline_report,
-                   server_bench, shard_bench, storage_bench, temporal_bench)
+                   replica_bench, retrieval_bench, server_bench,
+                   shard_bench, storage_bench, temporal_bench)
 
     benches = [
         materialize_bench.bench_materialize,
@@ -57,13 +56,6 @@ def main() -> None:
             failures += 1
             print(f"{bench.__name__},ERROR,\"{traceback.format_exc(limit=2)}\"",
                   file=sys.stderr, flush=True)
-    if args.only is None or "roofline" in (args.only or ""):
-        try:
-            for name, us, derived in roofline_report.run(args.dryrun_json):
-                print(f"{name},{us:.1f},\"{json.dumps(derived)}\"", flush=True)
-        except Exception:
-            failures += 1
-            traceback.print_exc()
     if failures:
         sys.exit(1)
 

@@ -292,21 +292,14 @@ def train_phase(uni, ev, gm, seed: int):
     import jax
     import numpy as np
     from repro.core import SnapshotBatchLoader
-    from repro.models import common as mc
-    from repro.models.gnn import GCNConfig, gnn_loss, gnn_param_defs
-    from repro.training.optim import OPTIMIZERS
-    from repro.training.trainer import make_train_step
-    from temporal_gnn_train import snapshot_stream
+    from temporal_gnn_train import (D_IN, init_params, snapshot_stream,
+                                    train_step)
 
     tmax = int(ev.time[-1])
     grid = [int(t) for t in np.linspace(tmax // 4, int(tmax * 0.8), 16)]
-    cfg = GCNConfig(d_in=16, d_hidden=32, n_layers=2, n_classes=2)
-    params = mc.init_params(gnn_param_defs(cfg), jax.random.PRNGKey(seed))
-    opt = OPTIMIZERS["adamw"](lr=5e-3)
-    opt_state = opt[0](params)
-    step_fn = jax.jit(make_train_step(lambda p, b: gnn_loss(p, b, cfg), opt))
+    params, opt_state = init_params(jax.random.PRNGKey(seed))
     loader = SnapshotBatchLoader(gm, grid, batch_size=4,
-                                 label_horizon=tmax // 10, d_in=cfg.d_in,
+                                 label_horizon=tmax // 10, d_in=D_IN,
                                  seed=seed)
     first = next(iter(loader))
     oracle = Oracle(uni, ev)
@@ -320,8 +313,9 @@ def train_phase(uni, ev, gm, seed: int):
     losses = []
     t0 = time.perf_counter()
     for _ in range(10):
-        params, opt_state, m = step_fn(params, opt_state, next(stream))
-        losses.append(float(m["loss"]))
+        params, opt_state, loss = train_step(params, opt_state,
+                                             next(stream))
+        losses.append(float(loss))
     check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
     print(f"  first loader window == replay; 10 GCN steps in "
           f"{time.perf_counter() - t0:.2f}s, loss {losses[0]:.4f} -> "
@@ -375,9 +369,8 @@ def serve_phase(uni, ev, gm, rng):
 
 def sharded_phase(uni, ev, gm, rng):
     import numpy as np
-    from repro.launch.hlo_analysis import COLLECTIVES
     from repro.launch.mesh import retrieval_mesh
-    from repro.runtime.jax_exec import (execute_singlepoint_jax,
+    from repro.runtime.jax_exec import (COLLECTIVES, execute_singlepoint_jax,
                                         land_singlepoint_sharded,
                                         lowered_retrieval_hlo, sharded_masks)
     mesh = retrieval_mesh(4)

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # Fast test subset: everything except the multi-second `slow` tests
-# (distributed subprocesses, reduced-model smoke runs).  Full suite:
+# that start subprocesses (distributed, shard processes).  Full suite:
 #   PYTHONPATH=src python -m pytest -x -q
 set -euo pipefail
 cd "$(dirname "$0")/.."

@@ -12,5 +12,4 @@ from .delta_apply import (FusedOut, delta_apply_chain,  # noqa: F401
                           delta_apply_chain_batched, delta_apply_chain_prefix,
                           delta_apply_chain_prefix_batched, delta_apply_fused,
                           delta_apply_fused_batched)
-from .flash_attention import attention  # noqa: F401
 from .segment_sum import bucket_edges, segment_sum  # noqa: F401

@@ -1,8 +1,8 @@
 """Kernel implementation policy: impl / interpret resolved once, from env.
 
-Every kernel family (``delta_apply``, ``segment_sum``, ``flash_attention``)
-exposes ``impl=`` (``"pallas"`` | ``"xla"``) and, for the Pallas path,
-``interpret=``.  The policy is resolved in one place:
+Every kernel family (``delta_apply``, ``segment_sum``) exposes ``impl=``
+(``"pallas"`` | ``"xla"``) and, for the Pallas path, ``interpret=``.  The
+policy is resolved in one place:
 
 * on a TPU backend the only default is the compiled Pallas kernel,
   ``("pallas", False)``; an environment override that asks for XLA or

@@ -63,6 +63,8 @@ def segment_sum(data: jnp.ndarray, seg_ids, num_segments: int, *,
         if impl == "xla":
             return segment_sum_ref(data, to_device(seg_ids), num_segments)
         if impl == "pallas":
+            if data.shape[0] == 0:      # no edge: nothing to gather
+                return jnp.zeros((num_segments, data.shape[-1]), data.dtype)
             if buckets is None:
                 buckets = bucket_edges(to_host(seg_ids), num_segments,
                                        block_n)

@@ -1,39 +1,13 @@
-"""Production mesh construction (assignment-mandated shapes).
+"""The device mesh of sharded snapshot retrieval.
 
 A function, not a module constant, so importing this module never touches
-jax device state.  Single pod: 16×16 = 256 chips (``data`` × ``model``);
-multi-pod: 2×16×16 = 512 chips with the leading ``pod`` axis as the
-cross-pod data-parallel dimension (DCN-ish axis on real hardware).
+jax device state.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    need = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < need:
-        raise RuntimeError(
-            f"need {need} devices for mesh {shape}; have {len(devices)} — "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            "any jax import (launch/dryrun.py does this)")
-    arr = np.asarray(devices[:need]).reshape(shape)
-    return Mesh(arr, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    need = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < need:
-        raise RuntimeError(f"need {need} devices, have {len(devices)}")
-    return Mesh(np.asarray(devices[:need]).reshape(shape), axes)
 
 
 def retrieval_mesh(partitions: int, axis: str = "data") -> Mesh:
@@ -41,4 +15,7 @@ def retrieval_mesh(partitions: int, axis: str = "data") -> Mesh:
     partition, so the ``word_cyclic`` layout row owned by partition ``p``
     lives on device ``p`` and the delta-apply chain runs collective-free
     (see :func:`repro.runtime.jax_exec.execute_singlepoint_sharded`)."""
-    return make_mesh((partitions,), (axis,))
+    devices = jax.devices()
+    if len(devices) < partitions:
+        raise RuntimeError(f"need {partitions} devices, have {len(devices)}")
+    return Mesh(np.asarray(devices[:partitions]), (axis,))

@@ -1,11 +1,13 @@
 """Serving launchers.
 
-* ``--mode query`` — the declarative wire protocol: newline-delimited
-  JSON :class:`~repro.api.document.GraphQuery` documents in (stdin or
-  ``--input``), JSON :class:`~repro.api.service.QueryResult` envelopes
-  out, with co-batched documents merged into one Steiner plan
-  (``--doc-batch``) — the documented ``--port 0`` stdin fallback of the
-  socket server, sharing its SessionCore code path;
+* ``--mode query`` (the default) — the declarative wire protocol:
+  newline-delimited JSON :class:`~repro.api.document.GraphQuery`
+  documents in (stdin or ``--input``), JSON
+  :class:`~repro.api.service.QueryResult` envelopes out, with co-batched
+  documents merged into one Steiner plan (``--doc-batch``) — the
+  documented ``--port 0`` stdin fallback of the socket server, sharing
+  its SessionCore code path; with ``--port > 0`` it is the socket server
+  below;
 * ``--mode server`` — the concurrent socket front end
   (launch/server.py): NDJSON sessions over TCP, cross-client co-batching
   inside a ``--window-ms`` window, deadline admission control
@@ -24,10 +26,7 @@
   rollovers) while reader threads issue snapshot/interval documents
   against epoch-pinned consistent views; reports sustained events/s,
   query latency under write pressure, and freshness lag, ending with one
-  machine-parseable ``INGEST_SUMMARY`` line (the CI smoke contract);
-* ``--mode model`` (default) — batched autoregressive decode for LM archs
-  (reduced config on CPU; the production mesh decode path is exercised by
-  dryrun.py) and batched CTR scoring for DIN.
+  machine-parseable ``INGEST_SUMMARY`` line (the CI smoke contract).
 """
 from __future__ import annotations
 
@@ -38,11 +37,6 @@ from typing import Iterable, Iterator
 
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-
-from ..configs.registry import family_of, get_arch, reduced_config
-from ..models import common as mc
 from ..runtime.compile_cache import enable_compile_cache
 
 
@@ -480,76 +474,11 @@ def serve_evolve(n_events: int, intervals: int, points: int, op: str,
     gm.close()
 
 
-def serve_lm(arch: str, batch: int, prompt_len: int, gen: int) -> None:
-    from ..models.transformer import model as tm
-    cfg = reduced_config(arch)
-    params = mc.init_params(tm.param_defs(cfg), jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (batch, prompt_len)),
-                         jnp.int32)
-    prefill = jax.jit(lambda p, t: tm.prefill_step(p, t, cfg))
-    decode = jax.jit(lambda p, c, t, l: tm.decode_step(p, c, t, l, cfg))
-
-    t0 = time.time()
-    last, caches = prefill(params, tokens)
-    # move prefill caches into a max-length decode cache
-    cache = tm.init_cache(cfg, batch, prompt_len + gen)
-    new_cache = []
-    for (ck, cv), (pk, pv) in zip(cache, caches):
-        if cfg.mla is not None:
-            pk_ = jnp.moveaxis(pk, 0, 0)
-            new_cache.append((ck.at[:, :, :prompt_len].set(pk),
-                              cv.at[:, :, :prompt_len].set(pv)))
-        else:
-            new_cache.append((ck.at[:, :, :, :prompt_len].set(pk),
-                              cv.at[:, :, :, :prompt_len].set(pv)))
-    cache = new_cache
-    prefill_s = time.time() - t0
-
-    out = []
-    tok = jnp.argmax(last, -1)[:, None].astype(jnp.int32)
-    t0 = time.time()
-    for i in range(gen):
-        logits, cache = decode(params, cache, tok, jnp.int32(prompt_len + i))
-        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        out.append(np.asarray(tok))
-    dt = time.time() - t0
-    print(f"prefill {batch}x{prompt_len}: {prefill_s*1000:.0f} ms; "
-          f"decode {gen} steps: {dt/gen*1000:.1f} ms/step "
-          f"({batch*gen/dt:.0f} tok/s)")
-    print("sample:", np.concatenate(out, 1)[0][:16].tolist())
-
-
-def serve_din(batch: int) -> None:
-    from ..models.recsys.din import din_forward, din_param_defs
-    cfg = reduced_config("din")
-    params = mc.init_params(din_param_defs(cfg), jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    S = cfg.seq_len
-    b = {"hist_goods": jnp.asarray(rng.integers(0, cfg.n_goods, (batch, S)), jnp.int32),
-         "hist_cates": jnp.asarray(rng.integers(0, cfg.n_cates, (batch, S)), jnp.int32),
-         "hist_mask": jnp.asarray(rng.random((batch, S)) < 0.8),
-         "target_goods": jnp.asarray(rng.integers(0, cfg.n_goods, batch), jnp.int32),
-         "target_cates": jnp.asarray(rng.integers(0, cfg.n_cates, batch), jnp.int32)}
-    fwd = jax.jit(lambda p, b_: din_forward(p, b_, cfg))
-    fwd(params, b)  # compile
-    t0 = time.time()
-    for _ in range(20):
-        fwd(params, b).block_until_ready()
-    dt = (time.time() - t0) / 20
-    print(f"din batch={batch}: {dt*1000:.2f} ms/batch "
-          f"({batch/dt:.0f} scores/s)")
-
-
-def main() -> None:
+def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=("model", "snapshots", "evolve",
-                                       "query", "ingest", "server"),
-                    default="model")
-    ap.add_argument("--arch", default="gemma3-1b")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
+    ap.add_argument("--mode", choices=("query", "server", "snapshots",
+                                       "evolve", "ingest"),
+                    default="query")
     ap.add_argument("--events", type=int, default=20_000,
                     help="snapshots mode: history size")
     ap.add_argument("--budget-mb", type=float, default=16.0,
@@ -628,7 +557,7 @@ def main() -> None:
                     choices=("pagerank", "components", "degree", "density",
                              "masks"),
                     help="evolve mode: incremental operator")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     enable_compile_cache()
     if args.mode == "server" or (args.mode == "query" and args.port > 0):
         serve_server(args.events, args.port, codec=args.codec,
@@ -652,12 +581,8 @@ def main() -> None:
         serve_ingest(args.events, args.duration, args.readers, args.group,
                      codec=args.codec, kv=args.kv, kv_dir=args.kv_dir,
                      hot_mb=args.hot_mb)
-    elif args.mode == "evolve":
-        serve_evolve(args.events, args.intervals, args.points, args.op)
-    elif family_of(args.arch) == "recsys":
-        serve_din(args.batch)
     else:
-        serve_lm(args.arch, args.batch, args.prompt, args.gen)
+        serve_evolve(args.events, args.intervals, args.points, args.op)
 
 
 if __name__ == "__main__":

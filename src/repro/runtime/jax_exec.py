@@ -872,9 +872,15 @@ def execute_singlepoint_sharded(dg: DeltaGraph, t: int, mesh: Mesh, *,
         dg, t, mesh, axis=axis, pool=pool, use_current=use_current))
 
 
+# HLO op names of cross-device collectives: none may appear in the
+# sharded retrieval step's lowered text.
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
 def lowered_retrieval_hlo(mesh: Mesh, K: int, Wp: int, axis: str = "data") -> str:
-    """Lowered HLO text of the sharded retrieval step (for the zero-
-    collective assertion and the dry-run report)."""
+    """Lowered HLO text of the sharded retrieval step, for the assertion
+    that it holds none of :data:`COLLECTIVES`."""
     Pn = mesh.shape[axis]
     fn = make_retrieval_fn(mesh, axis)
     args = (jax.ShapeDtypeStruct((Pn, Wp), jnp.uint32),

@@ -7,8 +7,8 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
-# real single-device CPU; only launch/dryrun.py (and the subprocesses in
-# test_distributed.py) fake 512/8 devices.
+# real single-device CPU; only the subprocesses in test_distributed.py fake
+# 8 devices.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np  # noqa: E402

@@ -208,39 +208,85 @@ def test_fused_singlepoint_vs_replay(seed):
 # ---------------------------------------------------------------------------
 
 
-def test_snapshot_batch_loader_oracle():
-    uni, ev, gm = _build(seed=11, n_events=200)
+def _loader_history(kind):
+    """The cell's kind of history (churn: deletions and transient edges)
+    or a growing co-authorship one, small."""
+    from repro.core.events import EV_DEL_EDGE
+    from repro.data.generators import churn_network, growing_network
+    if kind == "churn":
+        uni, ev = churn_network(n_initial_edges=90, n_events=300, seed=11,
+                                p_transient=0.05)
+        assert (ev.etype == EV_DEL_EDGE).any()
+        assert uni.edge_transient[:uni.num_edges].any()
+    else:
+        uni, ev = growing_network(n_events=300, seed=11)
+    gm = GraphManager(uni, ev, L=16, k=2, cache_bytes=0, prefetch_workers=0)
+    return uni, ev, gm
+
+
+def _loader_grid(grid, tmax):
+    """``(times, label_horizon)``: interior sorted times (7, so batches of
+    3 and 4 leave a partial last one); the history's ends, 0 and
+    ``tmax``, with a horizon that reaches past the end; or interior times
+    each given more than once, out of order."""
+    if grid == "interior":
+        return sorted({int(t) for t in np.linspace(1, tmax - 1, 7)}), 4
+    if grid == "ends":
+        return [int(t) for t in np.linspace(0, tmax, 12)], tmax // 2
+    inner = sorted({int(t) for t in np.linspace(1, tmax - 1, 6)})
+    return inner[::-1] + inner + inner[:3], 4
+
+
+def _degrees(uni, edge_mask):
+    d = np.zeros(uni.num_nodes, np.float32)
+    eid = np.nonzero(edge_mask)[0]
+    np.add.at(d, uni.edge_src[eid], 1)
+    np.add.at(d, uni.edge_dst[eid], 1)
+    return d
+
+
+@pytest.mark.parametrize("grid", ["interior", "ends", "repeated"])
+@pytest.mark.parametrize("batch_size", [1, 3, 4])
+@pytest.mark.parametrize("history", ["churn", "growing"])
+def test_snapshot_batch_loader_oracle(history, batch_size, grid):
+    uni, ev, gm = _loader_history(history)
     tmax = int(ev.time[-1])
-    times = list(range(0, tmax, max(1, tmax // 10)))
-    loader = SnapshotBatchLoader(gm, times, batch_size=3, label_horizon=4,
-                                 d_in=8)
+    times, hz = _loader_grid(grid, tmax)
+    loader = SnapshotBatchLoader(gm, times, batch_size=batch_size,
+                                 label_horizon=hz, d_in=8)
     N, E = uni.num_nodes, uni.num_edges
+    src, dst = uni.edge_src[:E], uni.edge_dst[:E]
+    distinct = sorted(set(times))
+    seen = []
     n_batches = 0
     for batch in loader:
         T = len(batch["times"])
+        seen += batch["times"]
+        assert batch["times"] == distinct[n_batches * batch_size:
+                                          (n_batches + 1) * batch_size]
         assert batch["x"].shape == (T, N, 8)
-        assert batch["edge_index"].shape == (2, 2 * E)
+        assert np.array_equal(np.asarray(batch["edge_index"]),
+                              [np.concatenate([src, dst]),
+                               np.concatenate([dst, src])])
         assert batch["edge_mask"].shape == (T, 2 * E)
         assert batch["labels"].shape == (T, N)
         for j, t in enumerate(batch["times"]):
             truth = replay(uni, ev, t)
             assert np.array_equal(
                 np.asarray(batch["label_mask"][j]) > 0, truth.node_mask)
-            rd = np.zeros(N, np.float32)
-            eid = np.nonzero(truth.edge_mask)[0]
-            np.add.at(rd, uni.edge_src[eid], 1)
-            np.add.at(rd, uni.edge_dst[eid], 1)
+            live = truth.edge_mask[:E].astype(np.float32)
+            assert np.array_equal(np.asarray(batch["edge_mask"][j]),
+                                  np.concatenate([live, live]))
+            rd = _degrees(uni, truth.edge_mask)
             assert np.array_equal(np.asarray(batch["x"][j, :, -1]), rd)
             assert int(batch["num_edges"][j]) == int(truth.edge_mask.sum())
-            fut = replay(uni, ev, t + 4)
-            fd = np.zeros(N, np.float32)
-            eid2 = np.nonzero(fut.edge_mask)[0]
-            np.add.at(fd, uni.edge_src[eid2], 1)
-            np.add.at(fd, uni.edge_dst[eid2], 1)
+            fd = _degrees(uni, replay(uni, ev, t + hz).edge_mask)
             assert np.array_equal(np.asarray(batch["labels"][j]),
                                   (fd > rd).astype(np.int32))
         n_batches += 1
-    assert n_batches == len(loader) == len(times) // 3
+    assert n_batches == len(loader) == len(distinct) // batch_size
+    if grid == "ends":
+        assert {0, tmax} <= set(seen) and hz > 0
     gm.close()
 
 

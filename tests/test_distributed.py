@@ -57,34 +57,6 @@ def test_sharded_retrieval_correct_and_collective_free():
     assert "OK" in out
 
 
-def test_multi_device_train_step_runs():
-    """A reduced LM train step actually executes SPMD on 8 devices."""
-    out = run_sub("""
-        import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.configs.registry import reduced_config
-        from repro.models import common as mc
-        from repro.models.transformer import model as tm
-        from repro.training.optim import OPTIMIZERS
-        from repro.training.trainer import make_train_step
-        cfg = reduced_config("yi-34b")
-        params = mc.init_params(tm.param_defs(cfg), jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((4, 2), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        tokens = jnp.asarray(np.random.default_rng(0).integers(
-            0, cfg.vocab, (8, 16)), jnp.int32)
-        opt = OPTIMIZERS["adamw"](lr=1e-3)
-        state = opt[0](params)
-        step = make_train_step(lambda p, b: tm.loss_fn(p, b, cfg), opt)
-        with jax.set_mesh(mesh):
-            tok_sh = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
-            p2, s2, m = jax.jit(step)(params, state, {"tokens": tok_sh})
-        assert np.isfinite(float(m["loss"]))
-        print("OK", float(m["loss"]))
-    """)
-    assert "OK" in out
-
-
 def test_elastic_replan():
     from repro.runtime.fault import elastic_replan
     before = elastic_replan(16, ["w0", "w1", "w2", "w3"])
@@ -124,15 +96,3 @@ def test_heartbeat_and_straggler():
     # first assignment comes from the largest-deficit partition (p=3)
     assert assigned[0].startswith("k3_")
 
-
-def test_gradient_compression_roundtrip():
-    import jax, jax.numpy as jnp, numpy as np
-    from repro.runtime.compression import compress_tree, decompress_tree
-    g = {"a": jnp.asarray(np.random.default_rng(0).standard_normal((64, 64)),
-                          jnp.float32) * 1e-3}
-    for kind in ("bf16", "int8"):
-        packed = compress_tree(g, kind=kind)
-        out = decompress_tree(packed, like=g)
-        err = float(jnp.abs(out["a"] - g["a"]).max()
-                    / (jnp.abs(g["a"]).max() + 1e-12))
-        assert err < 0.05, (kind, err)

@@ -143,3 +143,95 @@ def test_jax_engine_matches_oracle(snap):
         nm, em = execute_singlepoint_jax(gm.dg, t, impl=impl, pool=gm.pool)
         assert np.array_equal(nm, truth.node_mask)
         assert np.array_equal(em, truth.edge_mask)
+
+
+# ---------------------------------------------------------------------------
+# the served components solver against the union-find reference
+# ---------------------------------------------------------------------------
+
+
+def _path(n):
+    return np.arange(n - 1), np.arange(1, n)
+
+
+def _cc_graph(case):
+    """``(num_nodes, src, dst, node_mask, edge_mask)`` of a hand-built
+    graph, one per case."""
+    rng = np.random.default_rng(7)
+    if case == "no_live_edges":
+        N = 12
+        src, dst = rng.integers(0, N, 20), rng.integers(0, N, 20)
+        nodes = np.ones(N, bool)
+        nodes[[3, 8]] = False
+        return N, src, dst, nodes, np.zeros(20, bool)
+    if case == "isolated_and_one_edge":
+        N = 9
+        src, dst = np.array([2, 5, 0]), np.array([6, 7, 1])
+        return N, src, dst, np.ones(N, bool), np.array([True, False, False])
+    if case == "self_loops":
+        N = 8
+        src, dst = np.array([1, 3, 1, 6, 6]), np.array([1, 3, 4, 6, 2])
+        return N, src, dst, np.ones(N, bool), np.ones(5, bool)
+    if case == "dead_bridge":
+        # two paths, 0..9 and 10..19, whose only joining edge is dead
+        s0, d0 = _path(10)
+        src = np.concatenate([s0, s0 + 10, [9]])
+        dst = np.concatenate([d0, d0 + 10, [10]])
+        edges = np.ones(src.size, bool)
+        edges[-1] = False
+        return 20, src, dst, np.ones(20, bool), edges
+    if case == "long_path":
+        # label 0 floods one hop a sweep: 60 sweeps
+        N = 60
+        src, dst = _path(N)
+        return N, src, dst, np.ones(N, bool), np.ones(N - 1, bool)
+    if case == "star":
+        N = 40
+        src = np.full(N - 1, N - 1)
+        dst = np.arange(N - 1)
+        return N, src, dst, np.ones(N, bool), np.ones(N - 1, bool)
+    if case == "many_blocks":
+        N, E = 1500, 2400      # 12 blocks of 128 nodes
+        src, dst = rng.integers(0, N, E), rng.integers(0, N, E)
+        nodes = rng.random(N) < 0.9
+        edges = (rng.random(E) < 0.5) & nodes[src] & nodes[dst]
+        return N, src, dst, nodes, edges
+    if case == "length_boundary":
+        N, E = 300, 700
+        src, dst = rng.integers(0, N, E), rng.integers(0, N, E)
+        edges = np.zeros(E, bool)
+        edges[rng.choice(E, 512, replace=False)] = True
+        return N, src, dst, np.ones(N, bool), edges
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", ["no_live_edges", "isolated_and_one_edge",
+                                  "self_loops", "dead_bridge", "long_path",
+                                  "star", "many_blocks", "length_boundary"])
+def test_components_fixpoint_exact(case):
+    """``ComponentsOp``'s cold solve equals the union-find labels exactly:
+    the smallest live id of each component, int32 max for dead nodes."""
+    from types import SimpleNamespace
+
+    import analytics_reference as ref
+    from repro.core.temporal import ComponentsOp
+    from repro.graph.algorithms import fixpoint_lengths
+
+    N, src, dst, nodes, edges = _cc_graph(case)
+    src, dst = src.astype(np.int32), dst.astype(np.int32)
+    ctx = SimpleNamespace(edge_src=src, edge_dst=dst,
+                          universe=SimpleNamespace(num_nodes=N))
+    op = ComponentsOp()
+    got = op.init(ctx, SimpleNamespace(node_mask=nodes, edge_mask=edges), 0)
+    want = ref.components(src, dst, nodes, edges)
+    assert got.dtype == np.int32 and np.array_equal(got, want)
+    assert op.iters < op.max_iters
+    live = int(edges.sum())
+    assert op.edges == min(n for n in fixpoint_lengths(src.size)
+                           if n >= live)
+    if case == "long_path":
+        assert op.iters > 20
+    if case == "length_boundary":
+        assert op.edges == live == fixpoint_lengths(src.size)[0]
+    if case == "dead_bridge":
+        assert set(got.tolist()) == {0, 10}

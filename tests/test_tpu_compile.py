@@ -21,8 +21,7 @@ from repro.core.bitmaps import num_words
 from repro.kernels import (delta_apply_chain, delta_apply_chain_batched,
                            delta_apply_fused_batched)
 from repro.kernels.segment_sum.segment_sum import segment_sum_bucketed
-from repro.launch.hlo_analysis import COLLECTIVES
-from repro.runtime.jax_exec import make_retrieval_fn
+from repro.runtime.jax_exec import COLLECTIVES, make_retrieval_fn
 
 # growing_network(n_events=2_000_000, seed=0, attrs_on_add=False)
 NODE_SLOTS, EDGE_SLOTS = 600_437, 1_399_563
